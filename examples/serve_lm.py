@@ -2,7 +2,7 @@
 admission, cpoll notification — clients inject prompts, the engine prefils
 into free slots and decodes all active slots each tick.
 
-    PYTHONPATH=src python examples/serve_lm.py --requests 16 --arch rwkv6-1.6b
+    PYTHONPATH=src python examples/serve_lm.py --requests 16 --arch rwkv6-1.6b --reduced
 """
 import sys
 
@@ -21,13 +21,16 @@ def main():
                     help="decode through the shared KV page pool")
     ap.add_argument("--backend", default="auto",
                     choices=("auto", "pallas", "ref"))
+    ap.add_argument("--reduced", action="store_true",
+                    help="CPU test size of the config (2 layers, float32)")
     args = ap.parse_args()
     serve_mod.main([
         "--arch", args.arch,
         "--requests", str(args.requests),
         "--prompt-len", "12", "--gen-len", "8",
         "--backend", args.backend,
-    ] + (["--paged"] if args.paged else []))
+    ] + (["--paged"] if args.paged else [])
+      + (["--reduced"] if args.reduced else []))
 
 
 if __name__ == "__main__":

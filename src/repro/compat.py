@@ -1,8 +1,7 @@
-"""Version-portability shims — one home for API drift across jax pins.
+"""Version-portability shims — one home for API spellings call sites share.
 
-The repo pins jax 0.4.x in CI but must trace on newer jax too; anything
-whose import path or kwarg spelling moved between versions is wrapped here
-so call sites stay on the current-API spelling.
+The repo runs on the installed jax (pinned in pyproject); this module keeps
+the one wrapper call sites use, so a future API move changes one place.
 """
 from __future__ import annotations
 
@@ -12,22 +11,8 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True) -> Any:
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x spells
-    it ``jax.experimental.shard_map.shard_map(..., check_rep=...)`` (the
-    deprecation shim on ``jax`` raises AttributeError rather than
-    forwarding). Semantics of the flag are identical for our uses: disable
-    the per-output replication/varying-manual-axes check.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:
-            pass  # jax builds where jax.shard_map still takes check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map``; ``check_vma=False`` disables the per-output
+    varying-manual-axes check for bodies whose outputs are replicated by
+    construction."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -13,18 +13,12 @@ helpers that apply it:
 
 * Pallas kernels consume :func:`memory_space_for` to pick BlockSpec memory
   spaces (VMEM staging vs ANY/HBM-resident operands);
-* host offload uses JAX memory kinds (``pinned_host``) when the backend
-  supports them, mirroring the per-region TPH knob at registration time —
-  the paper's "configuration parameter set when registering a memory
-  region to the RNIC".
+* :class:`MemoryBudget` is the one ledger for the host DRAM + NVM tiers.
 """
 from __future__ import annotations
 
 import enum
 from dataclasses import dataclass
-from typing import Optional
-
-import jax
 
 VMEM_BYTES = 128 * 1024 * 1024  # v5e per-core VMEM ~128 MiB (we budget half)
 VMEM_BUDGET = VMEM_BYTES // 2
@@ -83,11 +77,12 @@ def plan(regions: list[Region], vmem_budget: int = VMEM_BUDGET) -> dict[str, Tie
 
 def memory_space_for(tier: Tier):
     """BlockSpec memory space for a Pallas operand in this tier."""
+    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if tier is Tier.VMEM:
         return pltpu.VMEM
-    return pltpu.ANY  # compiler-placed (HBM) — kernel DMAs tiles explicitly
+    return pl.ANY  # compiler-placed (HBM) — kernel DMAs tiles explicitly
 
 
 def kernel_operand_spaces(regions: list[Region],
@@ -132,19 +127,6 @@ def kvs_cache_bytes(cache_sets: int, cache_ways: int, key_words: int,
     region that must take the VMEM/DDIO-to-cache treatment whole, or the
     measured hit path degrades into another bulk walk."""
     return (cache_sets + 1) * cache_ways * (key_words + val_words + 1) * 4
-
-
-def device_put_tier(x, tier: Tier):
-    """Apply the placement to a live array (host tier uses memory kinds)."""
-    if tier is Tier.HOST:
-        try:
-            dev = jax.devices()[0]
-            return jax.device_put(
-                x, jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-            )
-        except Exception:  # backend without memory kinds: stay on device
-            return x
-    return x
 
 
 class MemoryBudget:

@@ -14,11 +14,16 @@ the batch (hashes, dedupe, way ranking — ALU work; see
 planned writes through VMEM with ``input_output_aliases`` so untouched rows
 stay resident:
 
-  pass 1 (``_commit_buckets``): bucket rows gathered at the target bucket,
+  pass 1 (``commit_buckets``): bucket rows gathered at the target bucket,
       the chosen way overwritten in VMEM, written back in place — entries
       are pre-sorted by target bucket so same-bucket writers share one
       staged block (the DDIO-style "hot line stays in cache" path);
-  pass 2 (``_write_rows``):     value rows streamed to their pool slots.
+  pass 2 (``write_rows``):     value rows streamed to their pool slots
+      (``rows.scatter``).
+
+Single rows of 2-D arrays (a query key, a bucket's pointer row, a pool
+row) move as the aligned tile that holds them — the layout Mosaic can
+stage on a TPU (see ``rows``).
 
 Dropped/no-op entries target the state's **resident** zero sentinel row
 (the ``mode="drop"`` analogue): ``KVState`` permanently carries one pad
@@ -40,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import placement
+from repro.kernels import rows
 
 
 # Placement-fed BlockSpec memory spaces: per-step staged blocks are
@@ -47,20 +53,46 @@ from repro.core import placement
 # arrays are streaming DMA targets.
 _spaces = placement.block_spaces
 
+# A bucket's (W, KW) keys and a cache set's lines are whole trailing dims
+# of their arrays; a request's key, a bucket's pointer row and a set's
+# meta row are single rows of 2-D arrays, so they move as the (_S, width)
+# tile holding them and the kernel picks row ``index % _S``.
+_S = rows.sublanes(jnp.int32)
 
-def _probe_kernel(h1_ref, h2_ref, keys_ref, bk1_ref, bp1_ref, bk2_ref, bp2_ref, out_ref):
-    q = keys_ref[0]  # (KW,)
-    bk1, bp1 = bk1_ref[0], bp1_ref[0]  # (W, KW), (W,)
-    bk2, bp2 = bk2_ref[0], bp2_ref[0]
-    eq1 = jnp.all(bk1 == q[None, :], axis=-1) & (bp1 >= 0)
-    eq2 = jnp.all(bk2 == q[None, :], axis=-1) & (bp2 >= 0)
-    hit1, hit2 = jnp.any(eq1), jnp.any(eq2)
-    p1 = jnp.max(jnp.where(eq1, bp1, -1))
-    p2 = jnp.max(jnp.where(eq2, bp2, -1))
-    found = hit1 | hit2
-    ptr = jnp.where(hit1, p1, p2)
-    out_ref[0, 0] = found.astype(jnp.int32)
-    out_ref[0, 1] = jnp.where(found, ptr, 0).astype(jnp.int32)
+
+def _col(row):
+    """(1, W) -> (W, 1) by a diagonal mask: a transpose Mosaic lowers as
+    broadcasts and a lane reduction."""
+    w = row.shape[1]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
+    return jnp.max(jnp.where(diag, row, jnp.iinfo(jnp.int32).min), axis=1,
+                   keepdims=True)
+
+
+def _match(ways_keys, q):
+    """(W, KW) == (1, KW) on every key word -> (W, 1) bool."""
+    return jnp.min(jnp.where(ways_keys == q, 1, 0), axis=1, keepdims=True) > 0
+
+
+def _bucket_ptr(bk, bp_row, q):
+    """Matched live pointer of one bucket as (1, 1); -1 on a miss."""
+    bp = _col(bp_row)
+    eq = _match(bk, q) & (bp >= 0)
+    return jnp.max(jnp.where(eq, bp, -1), axis=0, keepdims=True)
+
+
+def _probe_kernel(h1_ref, h2_ref, keys_ref, bk1_ref, bp1_ref, bk2_ref, bp2_ref,
+                  out_ref):
+    i = pl.program_id(0)
+    q = keys_ref[pl.ds(i % _S, 1), :]  # (1, KW)
+    p1 = _bucket_ptr(bk1_ref[0], bp1_ref[pl.ds(h1_ref[i] % _S, 1), :], q)
+    p2 = _bucket_ptr(bk2_ref[0], bp2_ref[pl.ds(h2_ref[i] % _S, 1), :], q)
+    found = (p1 >= 0) | (p2 >= 0)
+    ptr = jnp.where(found, jnp.where(p1 >= 0, p1, p2), 0)  # (1, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
+    out_ref[pl.ds(i % _S, 1), :] = jnp.where(lane == 0, found.astype(jnp.int32),
+                                             ptr)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -72,24 +104,25 @@ def probe(bucket_keys, bucket_ptr, keys, h1, h2, *, interpret: bool = True):
     b = keys.shape[0]
     w, kw = bucket_keys.shape[1], bucket_keys.shape[2]
     sp = _spaces(
-        {"query": kw * 4, "bucket": w * kw * 4, "bptr": w * 4, "out": 8}, {}
+        {"query": _S * kw * 4, "bucket": w * kw * 4, "bptr": _S * w * 4,
+         "out": _S * 8}, {}
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # h1, h2
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, kw), lambda i, h1, h2: (i, 0),
+            pl.BlockSpec((_S, kw), lambda i, h1, h2: (i // _S, 0),
                          memory_space=sp["query"]),
             pl.BlockSpec((1, w, kw), lambda i, h1, h2: (h1[i], 0, 0),
                          memory_space=sp["bucket"]),
-            pl.BlockSpec((1, w), lambda i, h1, h2: (h1[i], 0),
+            pl.BlockSpec((_S, w), lambda i, h1, h2: (h1[i] // _S, 0),
                          memory_space=sp["bptr"]),
             pl.BlockSpec((1, w, kw), lambda i, h1, h2: (h2[i], 0, 0),
                          memory_space=sp["bucket"]),
-            pl.BlockSpec((1, w), lambda i, h1, h2: (h2[i], 0),
+            pl.BlockSpec((_S, w), lambda i, h1, h2: (h2[i] // _S, 0),
                          memory_space=sp["bptr"]),
         ],
-        out_specs=pl.BlockSpec((1, 2), lambda i, h1, h2: (i, 0),
+        out_specs=pl.BlockSpec((_S, 2), lambda i, h1, h2: (i // _S, 0),
                                memory_space=sp["out"]),
     )
     out = pl.pallas_call(
@@ -101,21 +134,22 @@ def probe(bucket_keys, bucket_ptr, keys, h1, h2, *, interpret: bool = True):
     return out[:, 0].astype(bool), out[:, 1]
 
 
-def _cache_probe_kernel(cset_ref, keys_ref, ck_ref, cv_ref, cm_ref, out_ref):
-    del cset_ref  # consumed by the index maps
-    q = keys_ref[0]  # (KW,)
-    ck, cv, cm = ck_ref[0], cv_ref[0], cm_ref[0]  # (CW, KW), (CW, VW), (CW,)
-    eq = jnp.all(ck == q[None, :], axis=-1) & (cm > 0)  # (CW,)
-    hit = jnp.any(eq)
-    cw = cm.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, cw), 1)
-    way = jnp.max(jnp.where(eq[None, :], iota, -1))
+def _cache_probe_kernel(cset_ref, keys_ref, ck_ref, cv_ref, cm_ref,
+                        hw_ref, val_ref):
+    i = pl.program_id(0)
+    q = keys_ref[pl.ds(i % _S, 1), :]  # (1, KW)
+    cm = _col(cm_ref[pl.ds(cset_ref[i] % _S, 1), :])  # (CW, 1)
+    eq = _match(ck_ref[0], q) & (cm > 0)  # (CW, 1)
+    way_ids = jax.lax.broadcasted_iota(jnp.int32, eq.shape, 0)
+    way = jnp.max(jnp.where(eq, way_ids, -1), axis=0, keepdims=True)  # (1, 1)
+    hit = way >= 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
+    hw_ref[pl.ds(i % _S, 1), :] = jnp.where(lane == 0, hit.astype(jnp.int32),
+                                            jnp.maximum(way, 0))
     # masked sum over ways: at most one way matches (kvstore admits each
     # key once), so the sum IS the matched value — and zero on a miss
-    val = jnp.sum(jnp.where(eq[:, None], cv, 0), axis=0)  # (VW,)
-    out_ref[0, 0] = hit.astype(jnp.int32)
-    out_ref[0, 1] = jnp.where(hit, way, 0).astype(jnp.int32)
-    out_ref[0, 2:] = val
+    val_ref[pl.ds(i % _S, 1), :] = jnp.sum(jnp.where(eq, cv_ref[0], 0), axis=0,
+                                           keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -133,60 +167,45 @@ def cache_probe(cache_keys, cache_vals, cache_meta, keys, cset, *,
     b, kw = keys.shape
     cw, vw = cache_vals.shape[1], cache_vals.shape[2]
     sp = _spaces(
-        {"query": kw * 4, "cset_keys": cw * kw * 4, "cset_vals": cw * vw * 4,
-         "cset_meta": cw * 4, "out": (2 + vw) * 4},
+        {"query": _S * kw * 4, "cset_keys": cw * kw * 4,
+         "cset_vals": cw * vw * 4, "cset_meta": _S * cw * 4,
+         "out_hw": _S * 8, "out_val": _S * vw * 4},
         {},
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # cset
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, kw), lambda i, cset: (i, 0),
+            pl.BlockSpec((_S, kw), lambda i, cset: (i // _S, 0),
                          memory_space=sp["query"]),
             pl.BlockSpec((1, cw, kw), lambda i, cset: (cset[i], 0, 0),
                          memory_space=sp["cset_keys"]),
             pl.BlockSpec((1, cw, vw), lambda i, cset: (cset[i], 0, 0),
                          memory_space=sp["cset_vals"]),
-            pl.BlockSpec((1, cw), lambda i, cset: (cset[i], 0),
+            pl.BlockSpec((_S, cw), lambda i, cset: (cset[i] // _S, 0),
                          memory_space=sp["cset_meta"]),
         ],
-        out_specs=pl.BlockSpec((1, 2 + vw), lambda i, cset: (i, 0),
-                               memory_space=sp["out"]),
+        out_specs=[
+            pl.BlockSpec((_S, 2), lambda i, cset: (i // _S, 0),
+                         memory_space=sp["out_hw"]),
+            pl.BlockSpec((_S, vw), lambda i, cset: (i // _S, 0),
+                         memory_space=sp["out_val"]),
+        ],
     )
-    out = pl.pallas_call(
+    hw, vals = pl.pallas_call(
         _cache_probe_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 2 + vw), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((b, 2), jnp.int32),
+                   jax.ShapeDtypeStruct((b, vw), cache_vals.dtype)],
         interpret=interpret,
     )(cset, keys, cache_keys, cache_vals, cache_meta)
-    return out[:, 0].astype(bool), out[:, 1], out[:, 2:]
+    return hw[:, 0].astype(bool), hw[:, 1], vals
 
 
-def _fetch_kernel(ptr_ref, pool_ref, out_ref):
-    out_ref[...] = pool_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def fetch(pool, ptr, *, interpret: bool = True):
     """pool: (NP + 1, VW), row NP = the zero sentinel; ptr: (B,) int32
     (pre-clamped — misses resolve to the sentinel row). Returns (B, VW)."""
-    b = ptr.shape[0]
-    vw = pool.shape[1]
-    sp = _spaces({"row": vw * 4}, {})
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, vw), lambda i, ptr: (ptr[i], 0),
-                               memory_space=sp["row"])],
-        out_specs=pl.BlockSpec((1, vw), lambda i, ptr: (i, 0),
-                               memory_space=sp["row"]),
-    )
-    return pl.pallas_call(
-        _fetch_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, vw), pool.dtype),
-        interpret=interpret,
-    )(ptr, pool)
+    return rows.gather(pool, ptr, interpret=interpret)
 
 
 def get(state_bucket_keys, state_bucket_ptr, state_pool, keys, h1, h2, *,
@@ -206,20 +225,26 @@ def get(state_bucket_keys, state_bucket_ptr, state_pool, keys, h1, h2, *,
 
 def _commit_kernel(tb_ref, tw_ref, pv_ref, bkd_ref, bpd_ref, key_ref,
                    bk_ref, bp_ref, ko_ref, po_ref):
+    del bkd_ref, bpd_ref  # aliased destinations (pin the in-place update)
     i = pl.program_id(0)
-    # first writer of a bucket stages the current row; later same-bucket
-    # writers (consecutive after the wrapper's sort) reuse the VMEM copy
-    fresh = jnp.logical_or(i == 0, tb_ref[i] != tb_ref[i - 1])
-
-    @pl.when(fresh)
+    tb, prev = tb_ref[i], tb_ref[jnp.maximum(i - 1, 0)]
+    # first writer of a bucket (of a pointer tile) stages the current block;
+    # later writers (consecutive after the wrapper's sort) reuse the VMEM copy
+    @pl.when(jnp.logical_or(i == 0, tb != prev))
     def _():
         ko_ref[...] = bk_ref[...]
+
+    @pl.when(jnp.logical_or(i == 0, tb // _S != prev // _S))
+    def _():
         po_ref[...] = bp_ref[...]
 
-    w = bp_ref.shape[1]
-    wsel = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1) == tw_ref[i]
-    ko_ref[...] = jnp.where(wsel[..., None], key_ref[...][:, None, :], ko_ref[...])
-    po_ref[...] = jnp.where(wsel, pv_ref[i], po_ref[...])
+    w = ko_ref.shape[1]
+    q = key_ref[pl.ds(i % _S, 1), :]  # (1, KW)
+    wsel = jax.lax.broadcasted_iota(jnp.int32, (w, 1), 0) == tw_ref[i]
+    ko_ref[0] = jnp.where(wsel, q, ko_ref[0])
+    r = pl.ds(tb % _S, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    po_ref[r, :] = jnp.where(lane == tw_ref[i], pv_ref[i], po_ref[r, :])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -229,32 +254,29 @@ def commit_buckets(bucket_keys, bucket_ptr, keys, tb, tw, bptr_val, *,
     (keys[i], bptr_val[i]). ``bucket_keys``/``bucket_ptr`` carry their
     resident sentinel pad row at index NB that absorbs dropped entries
     (payloads pre-zeroed by ``insert``); ``tb`` must be sorted (the plan
-    sorts) so duplicate buckets are consecutive."""
+    sorts) so duplicate buckets — and pointer tiles — are consecutive."""
     b, kw = keys.shape
     w = bucket_ptr.shape[1]
     sp = _spaces(
-        {"key": kw * 4, "bucket": w * kw * 4, "bptr": w * 4},
+        {"key": _S * kw * 4, "bucket": w * kw * 4, "bptr": _S * w * 4},
         {"bucket_store": bucket_keys.nbytes, "bptr_store": bucket_ptr.nbytes},
     )
+    bucket = pl.BlockSpec((1, w, kw), lambda i, tb, tw, pv: (tb[i], 0, 0),
+                          memory_space=sp["bucket"])
+    bptr = pl.BlockSpec((_S, w), lambda i, tb, tw, pv: (tb[i] // _S, 0),
+                        memory_space=sp["bptr"])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # tb, tw, bptr_val
         grid=(b,),
         in_specs=[
             pl.BlockSpec(memory_space=sp["bucket_store"]),  # aliased dst
             pl.BlockSpec(memory_space=sp["bptr_store"]),  # aliased dst
-            pl.BlockSpec((1, kw), lambda i, tb, tw, pv: (i, 0),
+            pl.BlockSpec((_S, kw), lambda i, tb, tw, pv: (i // _S, 0),
                          memory_space=sp["key"]),
-            pl.BlockSpec((1, w, kw), lambda i, tb, tw, pv: (tb[i], 0, 0),
-                         memory_space=sp["bucket"]),
-            pl.BlockSpec((1, w), lambda i, tb, tw, pv: (tb[i], 0),
-                         memory_space=sp["bptr"]),
+            bucket,
+            bptr,
         ],
-        out_specs=[
-            pl.BlockSpec((1, w, kw), lambda i, tb, tw, pv: (tb[i], 0, 0),
-                         memory_space=sp["bucket"]),
-            pl.BlockSpec((1, w), lambda i, tb, tw, pv: (tb[i], 0),
-                         memory_space=sp["bptr"]),
-        ],
+        out_specs=[bucket, bptr],
     )
     return pl.pallas_call(
         _commit_kernel,
@@ -269,35 +291,12 @@ def commit_buckets(bucket_keys, bucket_ptr, keys, tb, tw, bptr_val, *,
     )(tb, tw, bptr_val, bucket_keys, bucket_ptr, keys, bucket_keys, bucket_ptr)
 
 
-def _write_kernel(wp_ref, pool_ref, val_ref, out_ref):
-    out_ref[...] = val_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def write_rows(pool, vals, wp, *, interpret: bool = True):
-    """Scatter pass 2: stream value row ``vals[i]`` to pool row ``wp[i]``.
-    ``pool`` carries its resident sentinel pad row at index NP for
-    no-write entries (payloads pre-zeroed by ``insert``)."""
-    b, vw = vals.shape
-    sp = _spaces({"val": vw * 4}, {"pool_store": pool.nbytes})
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # wp
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(memory_space=sp["pool_store"]),  # aliased dst
-            pl.BlockSpec((1, vw), lambda i, wp: (i, 0),
-                         memory_space=sp["val"]),
-        ],
-        out_specs=pl.BlockSpec((1, vw), lambda i, wp: (wp[i], 0),
-                               memory_space=sp["val"]),
-    )
-    return pl.pallas_call(
-        _write_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        input_output_aliases={1: 0},
-        interpret=interpret,
-    )(wp, pool, vals)
+    """Scatter pass 2: stream value row ``vals[i]`` to pool row ``wp[i]``
+    (``wp`` sorted). ``pool`` carries its resident sentinel pad row at
+    index NP for no-write entries (payloads pre-zeroed by ``insert``)."""
+    return rows.scatter(pool[None], vals[None], wp[None],
+                        interpret=interpret)[0]
 
 
 def insert(state_bucket_keys, state_bucket_ptr, state_pool, keys, vals,

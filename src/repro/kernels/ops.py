@@ -1,8 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: Pallas TPU kernels execute natively on TPU
-backends and in interpret mode (kernel body evaluated with jnp semantics)
-everywhere else — which is how this CPU container validates them. The
+``interpret`` defaults to auto: Pallas TPU kernels execute natively on a
+TPU backend and in interpret mode (kernel body evaluated with jnp
+semantics) on the CPU backend — which is how CPU test runs validate them.
+Any other backend is refused rather than silently interpreted. The
 pure-jnp oracles live in ``ref.py``; ``use_ref=True`` routes there (the
 dry-run uses the reference path so its HLO is XLA-analysable end to end).
 """
@@ -20,14 +21,20 @@ from repro.kernels import tx_commit as _tc
 
 
 def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run natively on tpu and interpreted on cpu; "
+            f"backend {backend!r} has neither path"
+        )
+    return backend == "cpu"
 
 
 def resolve_backend(backend=None):
     """Map the engine's ``kernel_backend`` knob to ``(use_ref, interpret)``.
 
     ``auto`` (and None) and ``pallas`` both take the Pallas path — native on
-    TPU, interpret mode elsewhere (which is how CPU containers validate the
+    TPU, interpret mode on CPU (which is how CPU test runs validate the
     kernels); ``ref`` routes to the pure-jnp oracles in :mod:`ref`.
     """
     if backend in (None, "auto", "pallas"):

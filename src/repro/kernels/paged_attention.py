@@ -2,11 +2,13 @@
 
 The serving engine's KV cache is the "large working set in server memory"
 of the paper; the page table is the data-structure walker's index. The grid
-walks (batch, kv-head, page): page ``p+1`` of a sequence is DMA'd HBM→VMEM
-while page ``p`` is being reduced (online softmax), the same
-memory-level-parallelism pattern as the other walkers. Query-head groups
-(GQA) ride along the kv-head block so the MXU sees a (G, hd) × (hd, PS)
-matmul per page.
+walks (batch, page): page ``p+1`` of a sequence is DMA'd HBM→VMEM while
+page ``p`` is being reduced (online softmax), the same
+memory-level-parallelism pattern as the other walkers. A page moves whole,
+all KV heads at once — its block ``(PS, KVH, hd)`` ends in the pool's own
+trailing dims, which is what Mosaic can stage — and the MXU sees one
+(KVH·G, hd) × (hd, PS·KVH) matmul per page, masked to each query head's
+own KV head (GQA groups ride along their KV head).
 
 The kernel emits its raw online-softmax state — unnormalized accumulator
 ``acc = Σ exp(s - m) v``, row max ``m``, and normalizer ``l = Σ exp(s - m)``
@@ -45,12 +47,12 @@ from repro.core import placement
 NEG_INF = -1e30
 
 
-def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, acc_out, m_out, l_out,
-            m_ref, l_ref, acc_ref):
+def _kernel(pt_ref, len_ref, q_ref, qh_ref, kh_ref, kp_ref, k_ref, v_ref,
+            acc_out, m_out, l_out, m_ref, l_ref, acc_ref):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    np_ = pl.num_programs(2)
-    ps = k_ref.shape[1]
+    p = pl.program_id(1)
+    np_ = pl.num_programs(1)
+    _, ps, kvh, hd = k_ref.shape
 
     @pl.when(p == 0)
     def _():
@@ -64,15 +66,18 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, acc_out, m_out, l_out,
 
     @pl.when(live)
     def _():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (PS, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (PS, hd)
-        s = q @ k.T  # (G, PS)
-        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
+        q = q_ref[0]  # (KVH*G, hd) f32, row = (kv head, group)
+        # the page's (PS, KVH, hd) block flattened to (PS*KVH, hd) rows,
+        # column c = (position c // KVH, kv head c % KVH)
+        k = k_ref[0].astype(jnp.float32).reshape(ps * kvh, hd)
+        v = v_ref[0].astype(jnp.float32).reshape(ps * kvh, hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        # every query row scores every head's keys; keep its own head's
+        mask = (qh_ref[...] == kh_ref[...]) & (page_start + kp_ref[...] < length)
+        s = jnp.where(mask, s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pexp = jnp.exp(s - m_new)
+        pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + pexp @ v
@@ -80,9 +85,9 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, acc_out, m_out, l_out,
 
     @pl.when(p == np_ - 1)
     def _():
-        acc_out[0, 0] = acc_ref[...].astype(acc_out.dtype)
-        m_out[0, 0] = m_ref[:, 0].astype(m_out.dtype)
-        l_out[0, 0] = l_ref[:, 0].astype(l_out.dtype)
+        acc_out[0] = acc_ref[...]
+        m_out[0] = m_ref[...]
+        l_out[0] = l_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -96,57 +101,69 @@ def paged_attention_stats(q, k_pages, v_pages, page_table, lengths, *,
     b, kvh, g, hd = q.shape
     n_pages, ps = k_pages.shape[0], k_pages.shape[1]
     maxp = page_table.shape[1]
+    rows = kvh * g
+    cols = ps * kvh
 
-    def pt_idx(bb, kv, p, pt, ln):
+    def pt_idx(bb, p, pt, ln):
         # dead entries (-1 / past the sequence length) resolve to the last
         # physical page — the zero sentinel when the pool allocates one —
         # instead of refetching live page 0; compute is skipped regardless.
         page = pt[bb, p]
         dead = (page < 0) | (p * ps >= ln[bb])
         return (jnp.where(dead, n_pages - 1, jnp.clip(page, 0, n_pages - 1)),
-                0, kv, 0)
+                0, 0, 0)
+
+    # head of each query row / of each flattened key column, and the key
+    # column's position in its page
+    q_head = (jnp.arange(rows, dtype=jnp.int32) // g)[:, None]
+    col = jnp.arange(cols, dtype=jnp.int32)[None, :]
+    k_head, k_pos = col % kvh, col // kvh
 
     sp = placement.block_spaces(
         {
-            "q": g * hd * 4,
-            "page": ps * hd * k_pages.dtype.itemsize,
-            "out": g * hd * 4,
+            "q": rows * hd * 4,
+            "ids": (rows + 2 * cols) * 4,
+            "page": cols * hd * k_pages.dtype.itemsize,
+            "out": rows * (hd + 2) * 4,
         },
         {},
     )
+    whole = lambda shape: pl.BlockSpec(
+        shape, lambda bb, p, pt, ln: (0,) * len(shape), memory_space=sp["ids"])
+    per_seq = lambda w: pl.BlockSpec(
+        (1, rows, w), lambda bb, p, pt, ln: (bb, 0, 0), memory_space=sp["out"])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # page_table, lengths
-        grid=(b, kvh, maxp),
+        grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda bb, kv, p, pt, ln: (bb, kv, 0, 0),
+            pl.BlockSpec((1, rows, hd), lambda bb, p, pt, ln: (bb, 0, 0),
                          memory_space=sp["q"]),
-            pl.BlockSpec((1, ps, 1, hd), pt_idx, memory_space=sp["page"]),
-            pl.BlockSpec((1, ps, 1, hd), pt_idx, memory_space=sp["page"]),
+            whole((rows, 1)),
+            whole((1, cols)),
+            whole((1, cols)),
+            pl.BlockSpec((1, ps, kvh, hd), pt_idx, memory_space=sp["page"]),
+            pl.BlockSpec((1, ps, kvh, hd), pt_idx, memory_space=sp["page"]),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda bb, kv, p, pt, ln: (bb, kv, 0, 0),
-                         memory_space=sp["out"]),
-            pl.BlockSpec((1, 1, g), lambda bb, kv, p, pt, ln: (bb, kv, 0),
-                         memory_space=sp["out"]),
-            pl.BlockSpec((1, 1, g), lambda bb, kv, p, pt, ln: (bb, kv, 0),
-                         memory_space=sp["out"]),
-        ],
+        out_specs=[per_seq(hd), per_seq(1), per_seq(1)],
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((b, kvh, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, rows, 1), jnp.float32),
         ),
         interpret=interpret,
-    )(page_table, lengths, q, k_pages, v_pages)
+    )(page_table, lengths, q.astype(jnp.float32).reshape(b, rows, hd),
+      q_head, k_head, k_pos, k_pages, v_pages)
+    return (acc.reshape(b, kvh, g, hd), m.reshape(b, kvh, g),
+            l.reshape(b, kvh, g))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1,31 +1,26 @@
-"""Pallas kernel: fused ORCA-TX commit — redo-log append + store scatter
+"""Pallas kernels: ORCA-TX commit — redo-log append + store scatter
 (§IV-B, the near-data transaction walk).
 
 The jnp half of a transaction batch (parse, first-claimant concurrency
 control, intra-tx write dedupe, log-slot ranking) runs ONCE in
-``core.transaction.plan_commit``; this kernel is the memory half every
+``core.transaction.plan_commit``; this module is the memory half every
 replica executes: append each proceeding transaction's log entry to its
-ring slot AND scatter its planned store writes, in one VMEM-staged
-aliased-in/out ``pallas_call`` (the ``hash_probe.insert`` scatter style).
+ring slot and scatter its planned store writes, as two aliased in-place
+row scatters (``rows.scatter``, the ``hash_probe.insert`` scatter style)
+over the whole chain at once.
 
-Grid = (B, max_ops): step (i, j) streams transaction i's log entry to
-``slot[i]`` (revisited across j — consecutive, so the staged block is
-written once per entry) and op j's value row to store row ``rows[i*M+j]``.
 The plan guarantees live targets are unique — concurrency control keeps
 proceeding transactions' write sets disjoint and the intra-tx dedupe keeps
-one writer per (tx, offset) — so no read-modify-write staging (and no
-target sort) is needed: this is a pure dual scatter. Dead entries
-(deferred transactions, dead ops, intra-tx shadowed writes) target the
-**resident** zero sentinel pad row that ``ReplicaState`` permanently
-carries past the live extent (``slot == LC`` / ``rows == NK``) — the same
-convention as the page pool's zero sentinel page (``serving.kv_cache``)
-and the KVS bucket/pool pad rows (``kernels.hash_probe``) — with their
-payloads zeroed, so nothing is concatenated onto or stripped off the
-O(state) log/store per replica commit.
-
-Operand memory spaces come from ``core.placement`` — per-step staged
-blocks (log entry, value row) are small and hot, the aliased log ring and
-store are bulk streaming targets.
+one writer per (tx, offset). Rows move as aligned tiles, so each scatter
+visits its targets in sorted order (one batch-sized argsort per replica)
+and stages every tile once. Dead entries (deferred transactions, dead
+ops, intra-tx shadowed writes) target the **resident** zero sentinel pad
+row that ``ReplicaState`` permanently carries past the live extent
+(``slot == LC`` / ``rows == NK``) — the same convention as the page
+pool's zero sentinel page (``serving.kv_cache``) and the KVS bucket/pool
+pad rows (``kernels.hash_probe``) — with their payloads zeroed, so
+nothing is concatenated onto or stripped off the O(state) log/store per
+replica commit.
 """
 from __future__ import annotations
 
@@ -33,26 +28,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import placement
-
-_spaces = placement.block_spaces
+from repro.kernels import rows as _rows
 
 
-def _commit_kernel(slot_ref, row_ref, log_dst_ref, store_dst_ref,
-                   entry_ref, val_ref, log_out_ref, store_out_ref):
-    # pure dual scatter: write-ahead log entry + planned store row. The
-    # aliased full-array refs (log_dst/store_dst) exist only to pin the
-    # in-place aliasing; the grid only stages the touched blocks.
-    log_out_ref[...] = entry_ref[...]
-    store_out_ref[...] = val_ref[0]
+def _by_row(dst, vals, idx, interpret):
+    """Sorted-target row scatter per replica: dst (R, N, W), vals (R, B, W),
+    idx (R, B). The plan keeps live targets unique and sentinel payloads
+    are zero, so the order among equal targets never matters."""
+    order = jnp.argsort(idx, axis=1, stable=True)
+    return _rows.scatter(
+        dst, jnp.take_along_axis(vals, order[..., None], axis=1),
+        jnp.take_along_axis(idx, order, axis=1), interpret=interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def commit(log, store, batch, values, slot, rows, *, interpret: bool = True):
-    """Fused planned-transaction commit.
+    """Planned-transaction commit: log append + store scatter.
 
     log: (LC + 1, TW); store: (NK + 1, VW) — the sentinel-resident
     ``ReplicaState`` layout, last row = the zero sentinel; batch: (B, TW)
@@ -61,65 +54,20 @@ def commit(log, store, batch, values, slot, rows, *, interpret: bool = True):
     per op (NK = the sentinel). Sentinel-targeted payloads are zeroed so
     dead duplicates write identical zeros (deterministic, sentinel stays
     zero). Returns the updated (log, store), same shapes in as out — the
-    aliased scatter updates the state in place, no padded copy."""
-    tw = log.shape[1]
-    vw = store.shape[1]
-    lc = log.shape[0] - 1
-    nk = store.shape[0] - 1
-    b, m = values.shape[0], values.shape[1]
-    batch = jnp.where((slot >= lc)[:, None], 0, batch)
-    values = jnp.where((rows >= nk).reshape(b, m)[..., None], 0, values)
-    sp = _spaces(
-        {"entry": tw * 4, "val": vw * 4},
-        {"log_store": log.nbytes, "store_store": store.nbytes},
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # slot, rows
-        grid=(b, m),
-        in_specs=[
-            pl.BlockSpec(memory_space=sp["log_store"]),  # aliased dst
-            pl.BlockSpec(memory_space=sp["store_store"]),  # aliased dst
-            pl.BlockSpec((1, tw), lambda i, j, slot, rows: (i, 0),
-                         memory_space=sp["entry"]),
-            pl.BlockSpec((1, 1, vw), lambda i, j, slot, rows: (i, j, 0),
-                         memory_space=sp["val"]),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, tw), lambda i, j, slot, rows: (slot[i], 0),
-                         memory_space=sp["entry"]),
-            pl.BlockSpec((1, vw), lambda i, j, slot, rows: (rows[i * m + j], 0),
-                         memory_space=sp["val"]),
-        ],
-    )
-    log_o, store_o = pl.pallas_call(
-        _commit_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(log.shape, log.dtype),
-            jax.ShapeDtypeStruct(store.shape, store.dtype),
-        ],
-        # aliases index the full pallas_call operand list (prefetch included)
-        input_output_aliases={2: 0, 3: 1},
+    aliased scatters update the state in place, no padded copy."""
+    log_o, store_o = commit_chain(
+        log[None], store[None], batch, values, slot[None], rows,
         interpret=interpret,
-    )(slot, rows, log, store, batch, values)
-    return log_o, store_o
-
-
-def _chain_commit_kernel(slot_ref, row_ref, log_dst_ref, store_dst_ref,
-                         entry_ref, val_ref, log_out_ref, store_out_ref):
-    # same pure dual scatter as _commit_kernel, with a leading replica dim
-    # on both payloads (values are per-replica so a dead replica's zeroed
-    # sentinel writes never leak into a live one's block)
-    log_out_ref[...] = entry_ref[...]
-    store_out_ref[...] = val_ref[0]
+    )
+    return log_o[0], store_o[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def commit_chain(log, store, batch, values, slot, rows, *,
                  interpret: bool = True):
-    """Whole-chain fused commit: ONE ``pallas_call`` covering every replica
-    of a local chain (grid (R, B, max_ops)) instead of a scan of
-    per-replica calls — the scan's xs/ys staging moved each replica's
+    """Whole-chain commit: one log scatter and one store scatter covering
+    every replica of a local chain (grid (R, entries)) instead of a scan
+    of per-replica calls — the scan's xs/ys staging moved each replica's
     whole log+store per round, which re-introduced the O(state) copies the
     resident sentinel layout exists to kill.
 
@@ -144,46 +92,8 @@ def commit_chain(log, store, batch, values, slot, rows, *,
         jnp.broadcast_to(batch[None], (r, b, tw)),
     )
     values_r = jnp.where(
-        rows.reshape(r, b, m)[..., None] >= nk, 0,
-        jnp.broadcast_to(values[None], (r, b, m, vw)),
+        (rows >= nk)[..., None], 0,
+        jnp.broadcast_to(values.reshape(1, b * m, vw), (r, b * m, vw)),
     )
-    slot_flat = slot.reshape(r * b)
-    rows_flat = rows.reshape(r * b * m)
-    sp = _spaces(
-        {"entry": tw * 4, "val": vw * 4},
-        {"log_store": log.nbytes, "store_store": store.nbytes},
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # slot_flat, rows_flat
-        grid=(r, b, m),
-        in_specs=[
-            pl.BlockSpec(memory_space=sp["log_store"]),  # aliased dst
-            pl.BlockSpec(memory_space=sp["store_store"]),  # aliased dst
-            pl.BlockSpec((1, 1, tw), lambda k, i, j, slot, rows: (k, i, 0),
-                         memory_space=sp["entry"]),
-            pl.BlockSpec((1, 1, 1, vw),
-                         lambda k, i, j, slot, rows: (k, i, j, 0),
-                         memory_space=sp["val"]),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, tw),
-                         lambda k, i, j, slot, rows: (k, slot[k * b + i], 0),
-                         memory_space=sp["entry"]),
-            pl.BlockSpec(
-                (1, 1, vw),
-                lambda k, i, j, slot, rows: (k, rows[k * b * m + i * m + j], 0),
-                memory_space=sp["val"]),
-        ],
-    )
-    log_o, store_o = pl.pallas_call(
-        _chain_commit_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(log.shape, log.dtype),
-            jax.ShapeDtypeStruct(store.shape, store.dtype),
-        ],
-        # aliases index the full pallas_call operand list (prefetch included)
-        input_output_aliases={2: 0, 3: 1},
-        interpret=interpret,
-    )(slot_flat, rows_flat, log, store, batch_r, values_r)
-    return log_o, store_o
+    return (_by_row(log, batch_r, slot, interpret),
+            _by_row(store, values_r, rows, interpret))

@@ -1,9 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this builds the real step function (train_step for train
@@ -29,6 +23,7 @@ Usage::
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -263,6 +258,12 @@ def _write(out_dir, cell_id, rec):
 
 
 def main():
+    # 512 host devices stand in for the production meshes; set here, not at
+    # import, so importing this module never changes a process's devices
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

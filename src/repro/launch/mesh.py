@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import ModelConfig
 from repro.parallel.sharding import ParallelContext
@@ -25,12 +26,12 @@ DCN_BW = 25e9  # B/s per host aggregate (cross-pod)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device CPU tests (needs host-device override)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_context(mesh, cfg: Optional[ModelConfig] = None, *, sp: bool = False,

@@ -6,8 +6,8 @@ analogue) → cpoll pointer-buffer scan notices them → round-robin admission
 into continuous-batching slots (prefill) → decode step per engine tick →
 finished generations land in response rings → clients poll + return credit.
 
-Reduced configs serve in seconds on CPU; the full configs lower through the
-same code path in the dry-run.
+By default the published config is served at its own widths and dtype;
+``--reduced`` serves the CPU test size (2 layers, float32) instead.
 """
 from __future__ import annotations
 
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.configs import get_config, reduced
 from repro.core import engine as eng
 from repro.core import placement
@@ -33,8 +34,27 @@ from repro.models import (
 from repro.parallel.sharding import local_context
 
 
+class EngineStep:
+    """The LM engine's jitted step with the weights bound as an argument.
+
+    ``step(state)`` runs it and ``step.lower(state)`` lowers it like a
+    jitted function. The weights are an argument of the compiled program,
+    not captured by it: jit bakes captured arrays into the program as
+    constants, which at published widths is a gigabyte of literals."""
+
+    def __init__(self, fn, params):
+        self.jitted = jax.jit(fn, donate_argnums=0)
+        self.params = params
+
+    def __call__(self, state):
+        return self.jitted(state, self.params)
+
+    def lower(self, state):
+        return self.jitted.lower(state, self.params)
+
+
 def build_engine(cfg, ctx, ecfg: eng.LMEngineConfig, params):
-    """(jitted step, initial state) for either decode substrate.
+    """(step, initial state) for either decode substrate.
 
     The engine state is DONATED at the jit boundary (``donate_argnums=0``):
     steady-state serving is a pure carry loop ``state = step(state)``, so
@@ -51,10 +71,8 @@ def build_engine(cfg, ctx, ecfg: eng.LMEngineConfig, params):
     if ecfg.paged:
         # page-pool decode: admission prefill lands prompt KV directly in
         # pages (default models.prefill_kv), no per-slot dense caches
-        step = jax.jit(
-            lambda s: eng.lm_engine_step(s, ecfg, cfg, ctx, params),
-            donate_argnums=0,
-        )
+        step = EngineStep(
+            lambda s, p: eng.lm_engine_step(s, ecfg, cfg, ctx, p), params)
         return step, uniquify(eng.lm_make_paged(ecfg, cfg, ctx))
 
     def prefill_fn(p, prompts):
@@ -64,11 +82,11 @@ def build_engine(cfg, ctx, ecfg: eng.LMEngineConfig, params):
     def decode_fn(p, toks, st):
         return decode_step(p, toks, st, cfg, ctx)
 
-    step = jax.jit(
-        lambda s: eng.lm_engine_step(
-            s, ecfg, cfg, ctx, params, prefill_fn, decode_fn
+    step = EngineStep(
+        lambda s, p: eng.lm_engine_step(
+            s, ecfg, cfg, ctx, p, prefill_fn, decode_fn
         ),
-        donate_argnums=0,
+        params,
     )
     state = eng.lm_make(ecfg, make_decode_state(cfg, ctx, ecfg.slots, ecfg.cache_len))
     return step, uniquify(state)
@@ -82,6 +100,10 @@ def main(argv=None):
     ap.add_argument("--gen-len", type=int, default=8)
     ap.add_argument("--queues", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the CPU test size of the config (2 layers, "
+                         "head_dim 8, vocab 128, float32) instead of its "
+                         "published widths and dtype")
     ap.add_argument("--paged", action="store_true",
                     help="decode through the shared KV page pool")
     ap.add_argument("--page-size", type=int, default=8)
@@ -124,7 +146,10 @@ def main(argv=None):
     if args.recover and args.snapshot_dir is None:
         ap.error("--recover requires --snapshot-dir")
 
-    cfg = reduced(get_config(args.arch)).replace(dtype="float32")
+    runtime.enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg).replace(dtype="float32")
     ctx = local_context()
     params = init_params(jax.random.key(args.seed), cfg, ctx)
     ecfg = eng.LMEngineConfig(
@@ -269,9 +294,10 @@ def main(argv=None):
         mgr.flush(state)
         mgr.wait()
     dt = time.time() - t0
+    dev = jax.devices()[0]
     print(f"served {recv}/{sent} requests ({tokens_out} tokens) in {ticks} "
           f"engine ticks ({dt:.1f}s wall, {recv / max(dt, 1e-9):.1f} req/s "
-          f"on CPU)")
+          f"on {dev.platform} {dev.device_kind})")
     if mgr is not None:
         committed = mgr.committed()
         print(f"  snapshots: {len(committed)} committed to "

@@ -167,7 +167,7 @@ def test_serve_recovers_with_host_pages():
                 "--queues", "2", "--paged", "--page-size", "2",
                 "--num-pages", "12", "--host-pages", "36", "--vary-caps",
                 "--snapshot-dir", d, "--snapshot-every", "4",
-                "--durability-mode", "adaptive"]
+                "--durability-mode", "adaptive", "--reduced"]
         out = subprocess.run(base, capture_output=True, text=True,
                              timeout=900, env=env)
         assert out.returncode == 0, out.stderr[-3000:]

@@ -28,14 +28,14 @@ def run_with_devices(code: str, n: int = 8, timeout: int = 600):
 def test_sharded_train_step_runs():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced
         from repro.launch.mesh import make_context
         from repro.models import init_params, loss_fn, postprocess_grads
         from repro.parallel.sharding import param_specs
         from repro.optim import AdamWConfig, init as opt_init, update as opt_update
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = reduced(get_config("qwen2.5-14b")).replace(
             dtype="float32", num_heads=4, num_kv_heads=2, head_dim=8, d_model=32)
         ctx = make_context(mesh, cfg)
@@ -72,12 +72,12 @@ def test_sharded_train_step_runs():
 def test_moe_ep_shardmap_matches_gather():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced
         from repro.models import moe as moe_mod
         from repro.parallel.sharding import ParallelContext
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         ctx = ParallelContext(mesh=mesh, use_ep=True)
         cfg = reduced(get_config("qwen3-moe-30b-a3b")).replace(
             dtype="float32", num_experts=8, num_experts_per_tok=2,
@@ -105,12 +105,12 @@ def test_moe_ep_shardmap_matches_gather():
 def test_chain_commit_spmd_matches_local():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.core import transaction as tx
 
         cfg = tx.TxConfig(num_keys=64, val_words=2, max_ops=3, chain_len=4,
                           log_capacity=32)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         chain = tx.make_chain(cfg)
         rng = np.random.default_rng(0)
         w = tx.tx_words(cfg)
@@ -149,13 +149,13 @@ def test_chain_commit_spmd_matches_local():
 def test_pipeline_parallel_matches_stack():
     run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced
         from repro.models import transformer as tf
         from repro.parallel.pipeline import pipeline_apply
         from repro.parallel.sharding import ParallelContext
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
         ctx = ParallelContext(mesh=mesh, pod_axis="pod")
         cfg = reduced(get_config("deepseek-7b")).replace(
             dtype="float32", num_layers=4, num_heads=2, num_kv_heads=2,
@@ -181,15 +181,15 @@ def test_elastic_checkpoint_reshard():
     """Save on a 4-device mesh, restore onto 2-device mesh (elastic)."""
     run_with_devices("""
         import tempfile, jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.checkpoint import save, restore
 
-        mesh4 = jax.make_mesh((4,), ("model",))
+        mesh4 = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
         w = jnp.arange(32.0).reshape(8, 4)
         wsh = jax.device_put(w, NamedSharding(mesh4, P("model", None)))
         with tempfile.TemporaryDirectory() as d:
             save(d, 1, {"w": wsh})
-            mesh2 = jax.make_mesh((2,), ("model",))
+            mesh2 = jax.make_mesh((2,), ("model",), axis_types=(AxisType.Auto,))
             out, _ = restore(d, 1, {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32)},
                              {"w": NamedSharding(mesh2, P(None, "model"))})
             np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(w))

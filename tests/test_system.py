@@ -43,7 +43,8 @@ def test_train_driver_with_grad_compression():
 
 def test_serve_driver_completes_all_requests():
     out = _run(["-m", "repro.launch.serve", "--arch", "qwen1.5-0.5b",
-                "--requests", "10", "--prompt-len", "8", "--gen-len", "4"])
+                "--requests", "10", "--prompt-len", "8", "--gen-len", "4",
+                "--reduced"])
     assert "served 10/10" in out
 
 
@@ -55,14 +56,15 @@ def test_dryrun_small_mesh_every_family():
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_config, reduced, SHAPES
     from repro.launch.mesh import make_context
     from repro.launch.hlo_analysis import analyze
     from repro.models import model as lm
     from repro.parallel.sharding import param_specs
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     for arch in ("qwen2.5-14b", "qwen3-moe-30b-a3b", "rwkv6-1.6b", "hymba-1.5b"):
         cfg = reduced(get_config(arch))
         ctx = make_context(mesh, cfg)

@@ -1,0 +1,146 @@
+"""Every Pallas kernel entry point compiles for a TPU v5e at real widths.
+
+Interpret mode (the rest of the suite) cannot see Mosaic's tiling rules:
+a block must end in tile multiples or in the array's own dims, and a DMA
+may not slice inside a tile. These tests lower each kernel with
+``interpret=False`` for a described (not attached) ``v5e:2x2`` chip, so
+the TPU compiler refuses here what it would refuse on the chip. Widths
+are the ones ``chip_smoke.py`` drives: a 1 KB-record KVS over 2^20 rows,
+a 3-replica TX chain over 2^20 64 B rows, 8 DLRM tables of 2^20 x 64 f32,
+and qwen1.5-0.5b's paged decode and prefill attention in bf16.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU compiler library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import (
+    embedding_reduce, flash_attention, hash_probe, paged_attention, tx_commit,
+)
+
+I32, F32, BF16 = jnp.int32, jnp.float32, jnp.bfloat16
+
+B = 256  # requests per engine batch
+NB, W, KW = 2 ** 18, 8, 2  # KVS buckets x ways, key words
+NP, VW = 2 ** 20, 256  # KVS pool rows, 1 KB values
+CS, CW = 1024, 4  # KVS hot-set cache sets x ways
+R, NK, TVW, M, LC = 3, 2 ** 20, 16, 8, 2 ** 16  # TX chain
+TW = 1 + M * (1 + TVW)  # TX log record words
+T, ROWS, D, L = 8, 2 ** 20, 64, 32  # DLRM tables x rows x dim, lookups
+QB, KVH, HD, PS, MAXP, PAGES = 8, 16, 64, 16, 10, 128  # qwen1.5-0.5b decode
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _kvs(s):
+    return dict(bk=s((NB + 1, W, KW), I32), bp=s((NB + 1, W), I32),
+                pool=s((NP + 1, VW), I32), keys=s((B, KW), I32),
+                idx=s((B,), I32), vals=s((B, VW), I32))
+
+
+def _tx(s, r):
+    return dict(log=s((r, LC + 1, TW), I32), store=s((r, NK + 1, TVW), I32),
+                batch=s((B, TW), I32), values=s((B, M, TVW), I32),
+                slot=s((r, B), I32), rows=s((B * M,), I32))
+
+
+def _kernel_call(name, s):
+    """(function, shape arguments) for one kernel entry point."""
+    native = dict(interpret=False)
+    if name == "probe":
+        k = _kvs(s)
+        return (lambda bk, bp, keys, h1, h2: hash_probe.probe(
+            bk, bp, keys, h1, h2, **native),
+            (k["bk"], k["bp"], k["keys"], k["idx"], k["idx"]))
+    if name == "cache_probe":
+        return (lambda ck, cv, cm, keys, cset: hash_probe.cache_probe(
+            ck, cv, cm, keys, cset, **native),
+            (s((CS + 1, CW, KW), I32), s((CS + 1, CW, VW), I32),
+             s((CS + 1, CW), I32), s((B, KW), I32), s((B,), I32)))
+    if name == "fetch":
+        k = _kvs(s)
+        return (lambda pool, ptr: hash_probe.fetch(pool, ptr, **native),
+                (k["pool"], k["idx"]))
+    if name == "commit_buckets":
+        k = _kvs(s)
+        return (lambda bk, bp, keys, tb, tw, pv: hash_probe.commit_buckets(
+            bk, bp, keys, tb, tw, pv, **native),
+            (k["bk"], k["bp"], k["keys"], k["idx"], k["idx"], k["idx"]))
+    if name == "write_rows":
+        k = _kvs(s)
+        return (lambda pool, vals, wp: hash_probe.write_rows(
+            pool, vals, wp, **native), (k["pool"], k["vals"], k["idx"]))
+    if name == "tx_commit":
+        t = _tx(s, 1)
+        return (lambda log, store, batch, values, slot, rows: tx_commit.commit(
+            log[0], store[0], batch, values, slot[0], rows, **native),
+            (t["log"], t["store"], t["batch"], t["values"], t["slot"],
+             t["rows"]))
+    if name == "commit_chain":
+        t = _tx(s, R)
+        return (lambda *a: tx_commit.commit_chain(*a, **native),
+                (t["log"], t["store"], t["batch"], t["values"], t["slot"],
+                 t["rows"]))
+    if name == "embedding_reduce":
+        n = B * T * L
+        return (lambda table, idx, seg: embedding_reduce.embedding_reduce(
+            table, idx, seg, B * T, **native),
+            (s((T * ROWS, D), F32), s((n,), I32), s((n,), I32)))
+    if name == "paged_attention_stats":
+        return (lambda *a: paged_attention.paged_attention_stats(*a, **native),
+                (s((QB, KVH, 1, HD), F32), s((PAGES + 1, PS, KVH, HD), BF16),
+                 s((PAGES + 1, PS, KVH, HD), BF16), s((QB, MAXP), I32),
+                 s((QB,), I32)))
+    if name == "flash_attention":
+        qkv = s((1, KVH, 128, HD), BF16)
+        return (lambda q, k, v: flash_attention.flash_attention(
+            q, k, v, **native), (qkv, qkv, qkv))
+    raise KeyError(name)
+
+
+KERNELS = ["probe", "cache_probe", "fetch", "commit_buckets", "write_rows",
+           "tx_commit", "commit_chain", "embedding_reduce",
+           "paged_attention_stats", "flash_attention"]
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(one_chip, name):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fn, args = _kernel_call(name, shape)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # what the kernel's operands and outputs hold, padding included, must
+    # fit one 16 GB v5e chip
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
