@@ -2,8 +2,6 @@
 
 Prints ``name,us_per_call,derived`` CSV rows and persists each app's rows
 to ``BENCH_<app>.json`` at the repo root (the per-PR perf trajectory).
-Roofline terms come from the dry-run artifacts (run
-``python -m repro.launch.dryrun --all`` first; see benchmarks/roofline.py).
 
 ``--smoke`` runs every benchmark for a couple of iterations only — the
 tier-1 fail-fast mode wired into ``scripts/tier1.sh --smoke``. Smoke runs
@@ -35,9 +33,7 @@ def main(argv=None) -> None:
 
     common.SMOKE = args.smoke
 
-    from benchmarks import (
-        bench_cpoll, bench_dlrm, bench_kvs, bench_lm, bench_tx, roofline,
-    )
+    from benchmarks import bench_cpoll, bench_dlrm, bench_kvs, bench_lm, bench_tx
 
     apps = [
         ("cpoll", "Fig. 7: cpoll vs polling", bench_cpoll),
@@ -53,8 +49,6 @@ def main(argv=None) -> None:
         if do_persist:
             path = common.persist(app, rows)
             print(f"# wrote {path}")
-    print("# --- Roofline (from dry-run artifacts) ---")
-    roofline.run()
 
 
 if __name__ == "__main__":

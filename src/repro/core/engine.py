@@ -47,6 +47,16 @@ from repro.core import status as st
 
 I32 = jnp.int32
 
+# Named scopes of the engine step's phases. They land in the compiled
+# program's op metadata only (the computation is unchanged), so a device
+# trace can charge each operation to the phase that issued it.
+SHED = "engine.shed"  # deadline shed phase
+POLL = "engine.poll"  # cpoll scan, round-robin schedule, cpoll consume
+GATHER = "engine.gather"  # request batch gathered and popped from the rings
+APU = "engine.apu"  # the app call (the apps open their own scopes inside)
+RESPOND = "engine.respond"  # responses enqueued, counters updated
+SCOPES = (SHED, POLL, GATHER, APU, RESPOND)
+
 
 class EngineConfig(NamedTuple):
     num_queues: int = 8
@@ -245,33 +255,41 @@ def engine_step(state: EngineState, app_fn: Callable, cfg: EngineConfig):
     # 0. deadline shed phase (only when the config designates a deadline
     # word): give up on doomed queue prefixes before spending budget
     if cfg.deadline_word >= 0:
-        state, n_timeout, n_shed = _shed_phase(state, cfg)
+        with jax.named_scope(SHED):
+            state, n_timeout, n_shed = _shed_phase(state, cfg)
     else:
         n_timeout = n_shed = jnp.zeros((), I32)
-    # 1. cpoll: O(4*Q)-byte notification scan
-    avail = state.cpoll.pointer_buffer - state.cpoll.ring_tracker
-    # 2. round-robin schedule within the step budget
-    take, sch = sched.schedule(state.sched, avail, cfg.budget)
-    cpo = cp.cpoll_partial(state.cpoll, jnp.arange(cfg.num_queues, dtype=I32), take)
-    # 3. gather the request batch from ring heads
-    qids, counts = sched.selected_queues(take)
-    payloads, srcq, valid = rb.gather_batch(state.req, qids, counts, cfg.budget)
-    req = rb.pop(state.req, qids, counts)
-    # 4. APU (kernel dispatch per cfg.kernel_backend)
-    app, responses = _call_app(app_fn, state.app, payloads, valid, cfg)
-    # 5. response path (+ response doorbells, batched)
-    resp = _enqueue_multi(state.resp, srcq, responses, valid)
-    n_served = jnp.sum(valid.astype(I32))
-    new = EngineState(
-        req=req, resp=resp, cpoll=cpo, sched=sch, app=app,
-        steps=state.steps + 1, served=state.served + n_served,
-        timed_out=state.timed_out, shed=state.shed,
-    )
-    return new, {
-        "served": n_served, "backlog": jnp.sum(avail - take),
-        "timed_out": n_timeout, "shed": n_shed,
-        **_app_stat_deltas(state.app, app),
-    }
+    with jax.named_scope(POLL):
+        # 1. cpoll: O(4*Q)-byte notification scan
+        avail = state.cpoll.pointer_buffer - state.cpoll.ring_tracker
+        # 2. round-robin schedule within the step budget
+        take, sch = sched.schedule(state.sched, avail, cfg.budget)
+        cpo = cp.cpoll_partial(
+            state.cpoll, jnp.arange(cfg.num_queues, dtype=I32), take
+        )
+    with jax.named_scope(GATHER):
+        # 3. gather the request batch from ring heads
+        qids, counts = sched.selected_queues(take)
+        payloads, srcq, valid = rb.gather_batch(state.req, qids, counts, cfg.budget)
+        req = rb.pop(state.req, qids, counts)
+    with jax.named_scope(APU):
+        # 4. APU (kernel dispatch per cfg.kernel_backend)
+        app, responses = _call_app(app_fn, state.app, payloads, valid, cfg)
+    with jax.named_scope(RESPOND):
+        # 5. response path (+ response doorbells, batched)
+        resp = _enqueue_multi(state.resp, srcq, responses, valid)
+        n_served = jnp.sum(valid.astype(I32))
+        new = EngineState(
+            req=req, resp=resp, cpoll=cpo, sched=sch, app=app,
+            steps=state.steps + 1, served=state.served + n_served,
+            timed_out=state.timed_out, shed=state.shed,
+        )
+        stats = {
+            "served": n_served, "backlog": jnp.sum(avail - take),
+            "timed_out": n_timeout, "shed": n_shed,
+            **_app_stat_deltas(state.app, app),
+        }
+    return new, stats
 
 
 def _enqueue_multi(ring: rb.RingState, queue_ids, payloads, mask):

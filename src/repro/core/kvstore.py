@@ -39,6 +39,14 @@ from repro.kernels import ops as kops
 I32 = jnp.int32
 U32 = jnp.uint32
 
+# Named scopes of the APU's KVS phases (op metadata only, see
+# ``engine.SCOPES``): the GET walk with its cache maintenance, the PUT plan,
+# and the PUT commit with its cache write-through.
+GET = "kvs.get"
+PLAN_PUT = "kvs.plan_put"
+COMMIT_PUT = "kvs.commit_put"
+SCOPES = (GET, PLAN_PUT, COMMIT_PUT)
+
 
 class KVConfig(NamedTuple):
     num_buckets: int = 1024  # power of two
@@ -502,22 +510,24 @@ def put(state: KVState, keys, vals, mask=None, *,
     matching ``app_step``) or ``ref`` (oracle gathers/scatters). Both
     backends write identical values, so they agree bit-for-bit.
     """
-    plan = plan_put(state, keys, mask, backend=backend)
+    with jax.named_scope(PLAN_PUT):
+        plan = plan_put(state, keys, mask, backend=backend)
     use_ref, interpret = kops.resolve_backend(backend or "auto")
-    bucket_keys, bucket_ptr, pool = kops.hash_put(
-        state.bucket_keys, state.bucket_ptr, state.pool, keys, vals,
-        plan.tb, plan.tw, plan.bptr_val, plan.wp,
-        plan.bucket_order, plan.row_order,
-        use_ref=use_ref, interpret=interpret,
-    )
-    state = state._replace(
-        bucket_keys=bucket_keys, bucket_ptr=bucket_ptr, pool=pool,
-        alloc=plan.alloc, dropped=plan.dropped,
-    )
-    if state.cache_sets > 0:
-        state = _put_write_through(
-            state, keys, vals, plan, use_ref, interpret
+    with jax.named_scope(COMMIT_PUT):
+        bucket_keys, bucket_ptr, pool = kops.hash_put(
+            state.bucket_keys, state.bucket_ptr, state.pool, keys, vals,
+            plan.tb, plan.tw, plan.bptr_val, plan.wp,
+            plan.bucket_order, plan.row_order,
+            use_ref=use_ref, interpret=interpret,
         )
+        state = state._replace(
+            bucket_keys=bucket_keys, bucket_ptr=bucket_ptr, pool=pool,
+            alloc=plan.alloc, dropped=plan.dropped,
+        )
+        if state.cache_sets > 0:
+            state = _put_write_through(
+                state, keys, vals, plan, use_ref, interpret
+            )
     return state, plan.ok
 
 
@@ -577,10 +587,11 @@ def app_step(state: KVState, payloads, valid, cfg: KVConfig, *,
     # Invalid and MALFORMED rows are masked out of both walks, so they
     # neither scatter garbage nor touch the cache (no admission, no
     # reference-bit bump).
-    state, get_vals, found = get(
-        state, keys, mask=valid & (op == OP_GET), backend=kernel_backend,
-        with_state=True,
-    )
+    with jax.named_scope(GET):
+        state, get_vals, found = get(
+            state, keys, mask=valid & (op == OP_GET), backend=kernel_backend,
+            with_state=True,
+        )
     state, put_ok = put(
         state, keys, vals, mask=valid & ~bad & (op == OP_PUT),
         backend=kernel_backend,

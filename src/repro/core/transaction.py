@@ -64,6 +64,12 @@ from repro.kernels import ops as kops
 
 I32 = jnp.int32
 
+# Named scopes of the APU's TX phases (op metadata only, see
+# ``engine.SCOPES``): validation and the batch plan, then the chain commit.
+PLAN = "tx.plan"
+COMMIT = "tx.commit"
+SCOPES = (PLAN, COMMIT)
+
 
 class TxConfig(NamedTuple):
     num_keys: int = 4096  # offset-addressed NVM region (rows)
@@ -331,11 +337,13 @@ def chain_commit_local(chain: ReplicaState, batch, cfg: TxConfig, mask=None,
     Default ``auto`` — the fused Pallas kernel (native on TPU, interpret
     elsewhere), matching ``tx_app.app_step``'s APU default; ``ref`` = the
     jnp oracle. Both agree bit-for-bit."""
-    plan = plan_commit(batch, cfg, mask)
+    with jax.named_scope(PLAN):
+        plan = plan_commit(batch, cfg, mask)
     use_ref, interpret = kops.resolve_backend(kernel_backend or "auto")
-    new_chain = chain_commit_apply(
-        chain, plan, use_ref=use_ref, interpret=interpret
-    )
+    with jax.named_scope(COMMIT):
+        new_chain = chain_commit_apply(
+            chain, plan, use_ref=use_ref, interpret=interpret
+        )
     proceed = plan.proceed
     deferred = (mask if mask is not None else jnp.ones_like(proceed)) & ~proceed
     return new_chain, proceed, deferred

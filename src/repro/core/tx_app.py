@@ -43,18 +43,20 @@ def app_step(chain: tx.ReplicaState, payloads, valid, cfg: tx.TxConfig, *,
     store-scatter kernel, ``ref`` = the jnp oracle; bit-for-bit identical)
     — the APU default, like ``kvstore.app_step``."""
     body = payloads[:, : tx.tx_words(cfg)]
-    n_raw = body[:, 0]
-    raw_ops = body[:, 1:].reshape(
-        body.shape[0], cfg.max_ops, 1 + cfg.val_words
-    )
-    raw_off = raw_ops[..., 0]
-    n_clip = jnp.clip(n_raw, 0, cfg.max_ops)
-    live_op = jnp.arange(cfg.max_ops)[None, :] < n_clip[:, None]
-    bad = valid & (
-        (n_raw < 0) | (n_raw > cfg.max_ops)
-        | jnp.any(live_op & ((raw_off < 0) | (raw_off >= cfg.num_keys)), axis=1)
-    )
-    live = valid & ~bad & (n_raw > 0)
+    with jax.named_scope(tx.PLAN):
+        n_raw = body[:, 0]
+        raw_ops = body[:, 1:].reshape(
+            body.shape[0], cfg.max_ops, 1 + cfg.val_words
+        )
+        raw_off = raw_ops[..., 0]
+        n_clip = jnp.clip(n_raw, 0, cfg.max_ops)
+        live_op = jnp.arange(cfg.max_ops)[None, :] < n_clip[:, None]
+        bad = valid & (
+            (n_raw < 0) | (n_raw > cfg.max_ops)
+            | jnp.any(live_op & ((raw_off < 0) | (raw_off >= cfg.num_keys)),
+                      axis=1)
+        )
+        live = valid & ~bad & (n_raw > 0)
     chain, committed, deferred = tx.chain_commit_local(
         chain, body, cfg, live, kernel_backend=kernel_backend
     )

@@ -10,9 +10,7 @@ production mesh, and records:
 * collective bytes       — parsed from the partitioned HLO (hlo_analysis)
 * MODEL_FLOPS = 6·N·D    — the useful-compute yardstick
 
-Artifacts land in experiments/dryrun/<arch>__<shape>__<mesh>.json; the
-roofline report (benchmarks/roofline.py) and EXPERIMENTS.md §Dry-run/§Roofline
-read them.
+Artifacts land in experiments/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage::
 

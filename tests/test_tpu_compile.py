@@ -7,13 +7,19 @@ may not slice inside a tile. These tests lower each kernel with
 the TPU compiler refuses here what it would refuse on the chip. Widths
 are the ones ``chip_smoke.py`` drives: a 1 KB-record KVS over 2^20 rows,
 a 3-replica TX chain over 2^20 64 B rows, 8 DLRM tables of 2^20 x 64 f32,
-and qwen1.5-0.5b's paged decode and prefill attention in bf16.
+and qwen1.5-0.5b's paged decode and prefill attention in bf16. The KVS and
+TX engine steps compile there too, at a small size, to check that each of
+their Pallas calls keeps the name ``bench/metrics/kernels.json`` matches and
+runs in its named phase.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and every test worker imports
 this file.
 """
 import os
+import sys
+import types
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +150,65 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     # fit one 16 GB v5e chip
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def _engine_step(app):
+    """The engine step of one app at a small size (the cells' widths), and
+    its state's shapes."""
+    from repro.core import engine, kvstore, transaction, tx_app
+
+    if app == "kvs":
+        cfg = kvstore.KVConfig(num_buckets=64, ways=8, key_words=6, val_words=256,
+                               pool_size=1024, cache_sets=16, cache_ways=4)
+        words, mod, make = kvstore.request_words(cfg), kvstore, kvstore.make
+    else:
+        cfg = transaction.TxConfig(num_keys=512, val_words=16, max_ops=8,
+                                   chain_len=4, log_capacity=64)
+        words, mod, make = tx_app.request_words(cfg), tx_app, transaction.make_chain
+    ecfg = engine.EngineConfig(num_queues=2, capacity=8, req_words=words,
+                               resp_words=words, budget=8, kernel_backend="auto")
+    app_fn = engine.bind_app(mod.app_step, cfg, ecfg)
+    state = jax.eval_shape(lambda: engine.make(ecfg, make(cfg)))
+    return (lambda s: engine.engine_step(s, app_fn, ecfg)), state
+
+
+# every Pallas call of the step: the kernels.json kernel it belongs to and
+# the named phase it runs in
+STEP_KERNELS = {
+    "kvs": {("probe", "hash_get", "kvs.get"), ("gather", "hash_get", "kvs.get"),
+            ("cache_probe", "cache_probe", "kvs.get"),
+            ("cache_probe", "cache_probe", "kvs.commit_put"),
+            ("probe", "hash_put", "kvs.plan_put"),
+            ("commit_buckets", "hash_put", "kvs.commit_put"),
+            ("scatter", "hash_put", "kvs.commit_put")},
+    "tx": {("scatter", "tx_commit", "tx.commit")},
+}
+
+
+@pytest.mark.parametrize("app", ["kvs", "tx"])
+def test_engine_step_kernels_keep_names_and_phases(one_chip, monkeypatch, app):
+    """Named scopes leave each Pallas call matched by exactly one kernel of
+    ``bench/metrics/kernels.json``, and put it in the phase it belongs to."""
+    root = str(Path(__file__).resolve().parents[1])  # the benchmark's tables
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.drivers.engine_step import kernel_paths
+    from bench.metrics import _ops
+    from bench.metrics._phases import phase_of
+    from bench.scopes import op_scopes
+    from bench.xplane import base_name
+    from repro.kernels import ops
+
+    # the described chip is not the default backend: take the native path
+    monkeypatch.setattr(ops, "_auto_interpret", lambda: False)
+    step, state = _engine_step(app)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state)
+    text = jax.jit(step).lower(state).compile().as_text()
+    paths, scopes = kernel_paths(text), op_scopes(text)
+    trace = types.SimpleNamespace(extra={"paths": paths})
+    found = set()
+    for instr in paths:
+        (kernel,) = [k for k in _ops.TABLE["kernels"] if _ops.in_kernel(trace, k, instr)]
+        found.add((base_name(instr), kernel, phase_of(scopes[instr])))
+    assert found == STEP_KERNELS[app]
