@@ -258,14 +258,19 @@ def get(state: KVState, keys, mask=None, *, backend: Optional[str] = "auto",
     return state, vals, found
 
 
-def _rank_within(ids, num: int):
-    """Stable rank of each element among equal ids (dispatch helper)."""
+def _rank_within(ids):
+    """Stable rank of each element among equal ids (dispatch helper).
+
+    Sorts the batch and counts each element's distance from the start of
+    its run of equal ids, so the cost is O(n log n) in the batch alone,
+    whatever range the ids are drawn from."""
     n = ids.shape[0]
     order = jnp.argsort(ids, stable=True)
-    sorted_ids = ids[order]
-    first = jnp.searchsorted(sorted_ids, jnp.arange(num), side="left")
-    rank_sorted = jnp.arange(n) - first[sorted_ids]
-    return jnp.zeros((n,), I32).at[order].set(rank_sorted.astype(I32))
+    s = ids[order]
+    pos = jnp.arange(n, dtype=I32)
+    start = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    first = jax.lax.cummax(jnp.where(start, pos, 0), axis=0)
+    return jnp.zeros((n,), I32).at[order].set(pos - first)
 
 
 def _nth_empty_way(bp_rows, rank):
@@ -340,7 +345,7 @@ def _cache_commit(state, keys, cset, refresh, way, admit, admit_vals,
 
     # ranked admission: the r-th admitting key of a set takes the r-th
     # victim way; sets with more admissions than victims drop the excess
-    r = _rank_within(jnp.where(admit, cset, cs), cs + 1)
+    r = _rank_within(jnp.where(admit, cset, cs))
     victim_ok = jnp.where(meta <= 1, -1, 0)  # _nth_empty_way convention
     vict = _nth_empty_way(victim_ok[cset], r)
     can = admit & (vict < cw)
@@ -426,7 +431,7 @@ def plan_put(state: KVState, keys, mask=None, *,
     # --- inserts: two-phase so primary and spill writers never collide ---
     # phase 1: primary-bucket inserters rank among themselves per bucket
     inserting = mask & is_first & ~exists
-    r1 = _rank_within(jnp.where(inserting, h1, nb), nb + 1)
+    r1 = _rank_within(jnp.where(inserting, h1, nb))
     w1 = _nth_empty_way(state.bucket_ptr[h1], r1)
     fits1 = inserting & (w1 < state.bucket_ptr.shape[1])
     spill = inserting & ~fits1
@@ -443,7 +448,7 @@ def plan_put(state: KVState, keys, mask=None, *,
     )
 
     # phase 2: spill inserters rank against the UPDATED occupancy
-    r2 = _rank_within(jnp.where(spill, h2, nb), nb + 1)
+    r2 = _rank_within(jnp.where(spill, h2, nb))
     w2 = _nth_empty_way(occ_ptr[h2], r2)
     fits2 = spill & (w2 < state.bucket_ptr.shape[1])
     drop = spill & ~fits2

@@ -184,3 +184,79 @@ def test_engine_app_request_format():
     s, resp = kv.app_step(s, get_req, jnp.array([True]), cfg)
     assert int(resp[0, 0]) == 1
     assert list(np.asarray(resp[0, 1:5])) == [9, 8, 7, 6]
+
+
+def _rank_oracle(ids, num):
+    """The searchsorted formulation: each id's first sorted position, looked
+    up in a table over every id in ``[0, num)``."""
+    n = ids.shape[0]
+    order = jnp.argsort(ids, stable=True)
+    sorted_ids = ids[order]
+    first = jnp.searchsorted(sorted_ids, jnp.arange(num), side="left")
+    rank_sorted = jnp.arange(n) - first[sorted_ids]
+    return jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
+
+
+_NB1 = 2**18 + 1  # the benchmark table's bucket ids, sentinel included
+
+
+def _rank_case(name):
+    rng = np.random.default_rng(14)
+    if name == "random":
+        return rng.integers(0, _NB1, 256)
+    if name == "all_equal":
+        return np.full(256, 77)
+    if name == "all_distinct":
+        return rng.permutation(_NB1)[:256]
+    if name == "mostly_sentinel":
+        ids = np.full(256, _NB1 - 1)
+        hot = rng.random(256) < 0.1
+        ids[hot] = rng.integers(0, 8, hot.sum())
+        return ids
+    if name == "single":
+        return np.array([5])
+    if name == "load_batch":
+        return rng.integers(0, _NB1, 16384)
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("case", ["random", "all_equal", "all_distinct",
+                                  "mostly_sentinel", "single", "load_batch"])
+def test_rank_within_matches_searchsorted(case):
+    ids = jnp.asarray(_rank_case(case), jnp.int32)
+    got = jax.jit(kv._rank_within)(ids)
+    want = jax.jit(_rank_oracle, static_argnums=1)(ids, _NB1)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for p in v if isinstance(v, (tuple, list)) else (v,):
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _loop_carries(jaxpr):
+    # a fixed-count loop (``jnp.searchsorted``'s) is a ``scan`` in the jaxpr;
+    # both it and ``while`` compile to an HLO while
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("while", "scan"):
+            yield from (tuple(v.aval.shape) for v in eqn.invars)
+        for sub in _sub_jaxprs(eqn):
+            yield from _loop_carries(sub)
+
+
+def test_plan_put_has_no_loop_over_every_bucket():
+    # the PUT plan at the benchmark's table size, traced from shapes alone:
+    # ranking its inserters must cost O(batch), never a loop over all ids
+    cfg = kv.KVConfig(num_buckets=2**18, ways=8, key_words=6, val_words=256,
+                      pool_size=2**20, cache_sets=1024, cache_ways=4)
+    state = jax.eval_shape(lambda: kv.make(cfg))
+    keys = jax.ShapeDtypeStruct((256, cfg.key_words), jnp.int32)
+    mask = jax.ShapeDtypeStruct((256,), jnp.bool_)
+    closed = jax.make_jaxpr(
+        lambda s, k, m: kv.plan_put(s, k, m, backend="ref"))(state, keys, mask)
+    carries = list(_loop_carries(closed.jaxpr))
+    assert not [c for c in carries if cfg.num_buckets + 1 in c]
