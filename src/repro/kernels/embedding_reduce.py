@@ -11,7 +11,14 @@ cache" path of C4).
 Rows move as aligned tiles — Mosaic cannot stage a single row of an
 ``(R, D)`` array — so step ``i`` brings in the ``(S, D)`` table tile that
 holds row ``idx[i]`` and adds that row into output row ``seg_ids[i]`` of
-the resident ``(S, D)`` output tile (``S`` = ``rows.sublanes``).
+the resident ``(S, D)`` output tile (``S`` = ``rows.sublanes``). The tile
+is widened to f32 in a VMEM scratch first: Mosaic cannot load one row of a
+16-bit tile at a dynamic offset (two rows share a sublane), so a bf16
+table's row is picked from the f32 copy.
+
+The ids and segment ids are prefetched into the 1 MiB of scalar memory,
+8 bytes a lookup: a call takes fewer than 131,072 lookups (a DLRM-DCNv2 step of
+256 samples has 54,784; ``tests/test_tpu_compile.py`` compiles it).
 
 Requirements: ``seg_ids`` must be non-decreasing (the natural (b, t, l)
 query layout already is), so each output tile is visited in one run and
@@ -30,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import rows
 
 
-def _kernel(idx_ref, seg_ref, table_ref, out_ref):
+def _kernel(idx_ref, seg_ref, table_ref, out_ref, tile_ref):
     i = pl.program_id(0)
     s_in, s_out = table_ref.shape[0], out_ref.shape[0]
     seg = seg_ref[i]
@@ -43,8 +50,8 @@ def _kernel(idx_ref, seg_ref, table_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     r = pl.ds(seg % s_out, 1)
-    row = table_ref[pl.ds(idx_ref[i] % s_in, 1), :]
-    out_ref[r, :] = out_ref[r, :] + row.astype(out_ref.dtype)
+    tile_ref[...] = table_ref[...].astype(tile_ref.dtype)
+    out_ref[r, :] = out_ref[r, :] + tile_ref[pl.ds(idx_ref[i] % s_in, 1), :]
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
@@ -66,6 +73,7 @@ def embedding_reduce(table, idx, seg_ids, num_segments: int, *, interpret: bool 
         out_specs=pl.BlockSpec(
             (s_out, d), lambda i, idx_ref, seg_ref: (seg_ref[i] // s_out, 0)
         ),
+        scratch_shapes=[pltpu.VMEM((s_in, d), jnp.float32)],
     )
     return pl.pallas_call(
         _kernel,

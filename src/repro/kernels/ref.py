@@ -32,6 +32,21 @@ def dlrm_embedding_reduce(tables, idx):
     return out
 
 
+def dlrm_pool(table, rows, pooling):
+    """Ragged DLRM pooling oracle: (R, D), (B, sum(pooling)) rows laid out
+    table by table -> (B, T, D) f32, each table's rows summed in lookup
+    order (an add chain, as in :func:`dlrm_embedding_reduce`)."""
+    g = table[rows].astype(F32)  # (B, N, D)
+    out, col = [], 0
+    for p in pooling:
+        acc = g[:, col]
+        for l in range(col + 1, col + p):
+            acc = acc + g[:, l]
+        out.append(acc)
+        col += p
+    return jnp.stack(out, axis=1)
+
+
 def hash_put(bucket_keys, bucket_ptr, pool, keys, vals, tb, tw, bptr_val, wp):
     """Commit phase of a planned batched PUT (see ``kvstore.plan_put``).
 

@@ -10,7 +10,8 @@ a 3-replica TX chain over 2^20 64 B rows, 8 DLRM tables of 2^20 x 64 f32,
 and qwen1.5-0.5b's paged decode and prefill attention in bf16. The KVS and
 TX engine steps compile there too, at a small size, to check that each of
 their Pallas calls keeps the name ``bench/metrics/kernels.json`` matches and
-runs in its named phase.
+runs in its named phase; the DLRM-DCNv2 step compiles at its benchmark
+cell's full size, to check that it reads its 6.78 GB table in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and every test worker imports
@@ -212,3 +213,53 @@ def test_engine_step_kernels_keep_names_and_phases(one_chip, monkeypatch, app):
         (kernel,) = [k for k in _ops.TABLE["kernels"] if _ops.in_kernel(trace, k, instr)]
         found.add((base_name(instr), kernel, phase_of(scopes[instr])))
     assert found == STEP_KERNELS[app]
+
+
+def test_dlrm_dcnv2_step_reads_the_table_in_place(one_chip, monkeypatch):
+    """The DLRM-DCNv2 engine step at the benchmark cell's shapes: one bf16
+    (26500127, 128) table (one chip's rows of MLPerf's 26 tables), 256
+    requests of 228 words, 54,784 lookups a step. The pooling kernel reads
+    the table where it lies: the step's temporaries hold no table-sized
+    buffer. Every phase is in the op metadata, the kernel in ``dlrm.embed``."""
+    root = str(Path(__file__).resolve().parents[1])  # the benchmark's driver
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.drivers.engine_step import kernel_paths
+    from repro.core import dlrm, engine
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_auto_interpret", lambda: False)
+    rows = (5000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 5000000,
+            383495, 405282, 10, 2209, 11938, 155, 4, 976, 14, 5000000, 5000000,
+            5000000, 590152, 12973, 108, 36)
+    pooling = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100,
+               27, 10, 3, 1, 1)
+    cfg = dlrm.DLRMConfig(num_tables=26, dim=128, dense_features=13,
+                          bottom=(512, 256), top=(1024, 1024, 512, 256, 1),
+                          table_rows=rows, pooling=pooling, interaction="dcn",
+                          cross_layers=3, cross_rank=512, matmul_precision="highest")
+    words = dlrm.request_words(cfg)
+    assert (sum(rows), sum(pooling), words) == (26500127, 214, 228)
+    ecfg = engine.EngineConfig(num_queues=8, capacity=64, req_words=words,
+                               resp_words=words, budget=B, kernel_backend="auto")
+    app_fn = engine.bind_app(dlrm.app_step, cfg, ecfg)
+
+    def app_state():
+        p = dlrm.init_params(jax.random.key(0), cfg)
+        return engine.make(ecfg, {**p, "tables": p["tables"].astype(BF16)})
+
+    state = jax.eval_shape(app_state)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state)
+    compiled = jax.jit(lambda s: engine.engine_step(s, app_fn, ecfg),
+                       donate_argnums=0).lower(state).compile()
+    table = sum(rows) * 128 * 2
+    mem = compiled.memory_analysis()
+    assert table <= mem.argument_size_in_bytes < table + 0.1e9
+    assert mem.temp_size_in_bytes < 0.1e9  # no copy of the 6.78 GB table
+    text = compiled.as_text()
+    (path,) = kernel_paths(text).values()
+    assert f"/{dlrm.EMBED}/" in path
+    assert f"s32[{B * sum(pooling)}]" in text  # one call pools every lookup
+    for scope in dlrm.SCOPES:
+        assert f"/{scope}/" in text
