@@ -89,9 +89,8 @@ def kernel_operand_spaces(regions: list[Region],
                           vmem_budget: int = VMEM_BUDGET) -> dict:
     """BlockSpec memory spaces for a kernel's operands, keyed by region name.
 
-    The Pallas wrappers (hash_probe, paged_attention, embedding_reduce)
-    declare one Region per operand — per-step staged blocks are small and
-    hot, bulk walked or scattered arrays are streaming — and consume the
+    The Pallas wrappers (hash_probe, paged_attention) declare one Region
+    per staged operand block through :func:`block_spaces` and consume the
     same Fig. 5 decision the host-side placement applies: VMEM-tier regions
     become pipelined VMEM staging blocks, everything else stays
     compiler-placed (ANY/HBM), with the kernel's index maps doing the
@@ -101,22 +100,17 @@ def kernel_operand_spaces(regions: list[Region],
     return {name: memory_space_for(t) for name, t in tiers.items()}
 
 
-def block_spaces(block_bytes: dict, bulk_bytes: dict,
-                 vmem_budget: int = VMEM_BUDGET) -> dict:
+def block_spaces(block_bytes: dict, vmem_budget: int = VMEM_BUDGET) -> dict:
     """Placement-fed BlockSpec memory spaces for a kernel's operands.
 
     ``block_bytes`` names per-grid-step staged blocks (small + hot — every
-    step touches them: they get the VMEM/DDIO-to-cache treatment);
-    ``bulk_bytes`` names bulk walked/scattered/aliased arrays (streaming —
-    they stay compiler-placed and the kernel's index maps DMA tiles
-    explicitly). The shared entry point for hash_probe's bucket walks and
-    paged_attention's page-pool walk."""
-    regions = [
-        Region(n, nb, access_rate_hz=1e6) for n, nb in block_bytes.items()
-    ] + [
-        Region(n, nb, streaming=True) for n, nb in bulk_bytes.items()
-    ]
-    return kernel_operand_spaces(regions, vmem_budget)
+    grid step touches them: they get the VMEM/DDIO-to-cache treatment). The
+    shared entry point for hash_probe's bucket walks and paged_attention's
+    page-pool walk."""
+    return kernel_operand_spaces(
+        [Region(n, nb, access_rate_hz=1e6) for n, nb in block_bytes.items()],
+        vmem_budget,
+    )
 
 
 def kvs_cache_bytes(cache_sets: int, cache_ways: int, key_words: int,

@@ -12,7 +12,12 @@ The PUT commit (``insert``) is the scatter mirror: the jitted wrapper plans
 the batch (hashes, dedupe, way ranking — ALU work; see
 ``kvstore.plan_put``), then two scalar-prefetch scatter passes stream the
 planned writes through VMEM with ``input_output_aliases`` so untouched rows
-stay resident:
+stay where they are. Each destination is passed to its ``pallas_call``
+once, as the blocked operand aliased to the output, and updated in place
+(a second, read-only pass of the same array would make XLA copy all of it
+before every call). The narrow bucket arrays are still relayouted by XLA
+around each call, from the runtime's compact layout into the row-major
+one a ``tpu_custom_call`` takes and back, until the state is lane-dense:
 
   pass 1 (``commit_buckets``): bucket rows gathered at the target bucket,
       the chosen way overwritten in VMEM, written back in place — entries
@@ -49,8 +54,7 @@ from repro.kernels import rows
 
 
 # Placement-fed BlockSpec memory spaces: per-step staged blocks are
-# small + hot (every grid step touches them), bulk scattered/aliased
-# arrays are streaming DMA targets.
+# small + hot (every grid step touches them).
 _spaces = placement.block_spaces
 
 # A bucket's (W, KW) keys and a cache set's lines are whole trailing dims
@@ -105,7 +109,7 @@ def probe(bucket_keys, bucket_ptr, keys, h1, h2, *, interpret: bool = True):
     w, kw = bucket_keys.shape[1], bucket_keys.shape[2]
     sp = _spaces(
         {"query": _S * kw * 4, "bucket": w * kw * 4, "bptr": _S * w * 4,
-         "out": _S * 8}, {}
+         "out": _S * 8}
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # h1, h2
@@ -169,8 +173,7 @@ def cache_probe(cache_keys, cache_vals, cache_meta, keys, cset, *,
     sp = _spaces(
         {"query": _S * kw * 4, "cset_keys": cw * kw * 4,
          "cset_vals": cw * vw * 4, "cset_meta": _S * cw * 4,
-         "out_hw": _S * 8, "out_val": _S * vw * 4},
-        {},
+         "out_hw": _S * 8, "out_val": _S * vw * 4}
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # cset
@@ -223,9 +226,8 @@ def get(state_bucket_keys, state_bucket_ptr, state_pool, keys, h1, h2, *,
     return jnp.where(found[:, None], vals, 0), found
 
 
-def _commit_kernel(tb_ref, tw_ref, pv_ref, bkd_ref, bpd_ref, key_ref,
-                   bk_ref, bp_ref, ko_ref, po_ref):
-    del bkd_ref, bpd_ref  # aliased destinations (pin the in-place update)
+def _commit_kernel(tb_ref, tw_ref, pv_ref, key_ref, bk_ref, bp_ref, ko_ref,
+                   po_ref):
     i = pl.program_id(0)
     tb, prev = tb_ref[i], tb_ref[jnp.maximum(i - 1, 0)]
     # first writer of a bucket (of a pointer tile) stages the current block;
@@ -254,12 +256,17 @@ def commit_buckets(bucket_keys, bucket_ptr, keys, tb, tw, bptr_val, *,
     (keys[i], bptr_val[i]). ``bucket_keys``/``bucket_ptr`` carry their
     resident sentinel pad row at index NB that absorbs dropped entries
     (payloads pre-zeroed by ``insert``); ``tb`` must be sorted (the plan
-    sorts) so duplicate buckets — and pointer tiles — are consecutive."""
+    sorts) so duplicate buckets — and pointer tiles — are consecutive.
+
+    ``bucket_keys`` and ``bucket_ptr`` are each passed once, as the blocked
+    operand aliased to its output, and updated in place: a bucket block
+    (pointer tile) is read by the first writer of its run and written back
+    when the run ends, and the sort keeps every block in one run, so no
+    block is read after its own write-back."""
     b, kw = keys.shape
     w = bucket_ptr.shape[1]
     sp = _spaces(
-        {"key": _S * kw * 4, "bucket": w * kw * 4, "bptr": _S * w * 4},
-        {"bucket_store": bucket_keys.nbytes, "bptr_store": bucket_ptr.nbytes},
+        {"key": _S * kw * 4, "bucket": w * kw * 4, "bptr": _S * w * 4}
     )
     bucket = pl.BlockSpec((1, w, kw), lambda i, tb, tw, pv: (tb[i], 0, 0),
                           memory_space=sp["bucket"])
@@ -269,12 +276,10 @@ def commit_buckets(bucket_keys, bucket_ptr, keys, tb, tw, bptr_val, *,
         num_scalar_prefetch=3,  # tb, tw, bptr_val
         grid=(b,),
         in_specs=[
-            pl.BlockSpec(memory_space=sp["bucket_store"]),  # aliased dst
-            pl.BlockSpec(memory_space=sp["bptr_store"]),  # aliased dst
             pl.BlockSpec((_S, kw), lambda i, tb, tw, pv: (i // _S, 0),
                          memory_space=sp["key"]),
-            bucket,
-            bptr,
+            bucket,  # bucket_keys, aliased to output 0
+            bptr,  # bucket_ptr, aliased to output 1
         ],
         out_specs=[bucket, bptr],
     )
@@ -286,9 +291,9 @@ def commit_buckets(bucket_keys, bucket_ptr, keys, tb, tw, bptr_val, *,
             jax.ShapeDtypeStruct(bucket_ptr.shape, bucket_ptr.dtype),
         ],
         # aliases index the full pallas_call operand list (prefetch included)
-        input_output_aliases={3: 0, 4: 1},
+        input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(tb, tw, bptr_val, bucket_keys, bucket_ptr, keys, bucket_keys, bucket_ptr)
+    )(tb, tw, bptr_val, keys, bucket_keys, bucket_ptr)
 
 
 def write_rows(pool, vals, wp, *, interpret: bool = True):
@@ -311,7 +316,10 @@ def insert(state_bucket_keys, state_bucket_ptr, state_pool, keys, vals,
     duplicate targets share a staged VMEM block (``bucket_order`` /
     ``row_order`` come precomputed from the plan; recomputed here only for
     direct calls), and the aliased scatter passes update the state in
-    place. Returns (bucket_keys, bucket_ptr, pool), same shapes in as out.
+    place: each state array is passed to its call once. XLA still
+    relayouts the narrow bucket arrays around the call until the state is
+    lane-dense. Returns (bucket_keys, bucket_ptr, pool), same shapes in as
+    out.
     """
     nb = state_bucket_keys.shape[0] - 1
     np_ = state_pool.shape[0] - 1

@@ -98,7 +98,10 @@ def hash_put(bucket_keys, bucket_ptr, pool, keys, vals, tb, tw, bptr_val, wp,
 
     State arrays are in the sentinel-resident ``KVState`` layout
     ((NB+1)/(NP+1) rows) and come back the same shape — neither backend
-    materializes a padded copy. ``bucket_order``/``row_order`` are the
+    materializes a padded copy. The Pallas path passes each state array to
+    its scatter once and updates it in place; XLA still relayouts the
+    narrow bucket arrays around the call from the runtime's compact layout
+    until the state is lane-dense. ``bucket_order``/``row_order`` are the
     plan's precomputed target sort orders (Pallas staging only; the
     scatter oracle is order-independent). Returns the updated
     (bucket_keys, bucket_ptr, pool) arrays."""
@@ -119,9 +122,12 @@ def tx_commit(log, store, batch, values, slot, rows, *,
     of a planned transaction batch (``core.transaction.plan_commit``).
 
     ``log``/``store`` are in the sentinel-resident ``ReplicaState`` layout
-    ((LC+1)/(NK+1) rows) and come back the same shape — no padded copy.
-    Returns the updated (log, store). Both backends zero sentinel-targeted
-    payloads (slot == LC / rows == NK) and agree bit-for-bit."""
+    ((LC+1)/(NK+1) rows) and come back the same shape. The Pallas path
+    passes each to its scatter once and updates it in place; XLA still
+    relayouts both around the call from the runtime's compact layout until
+    the state is lane-dense. Returns the updated (log, store). Both
+    backends zero sentinel-targeted payloads (slot == LC / rows == NK) and
+    agree bit-for-bit."""
     if use_ref:
         return _ref.tx_commit(log, store, batch, values, slot, rows)
     it = _auto_interpret() if interpret is None else interpret
@@ -134,11 +140,11 @@ def tx_commit_chain(log, store, batch, values, slot, rows, *,
     one batched dual scatter (``transaction.chain_commit_apply``).
 
     log: (R, LC+1, TW); store: (R, NK+1, VW) — sentinel-resident chain
-    layout, same shapes out, aliased in place on the Pallas path; slot:
-    (R, B) per-replica log slots; rows: (B*M,) shared store rows, or
-    (R, B*M) per-replica rows (chain shortening retargets a dead
-    replica's ops at its sentinel). Both backends agree bit-for-bit with
-    a per-replica :func:`tx_commit` loop."""
+    layout, same shapes out, each passed once to its scatter and updated
+    in place on the Pallas path; slot: (R, B) per-replica log slots; rows:
+    (B*M,) shared store rows, or (R, B*M) per-replica rows (chain
+    shortening retargets a dead replica's ops at its sentinel). Both
+    backends agree bit-for-bit with a per-replica :func:`tx_commit` loop."""
     if use_ref:
         return _ref.tx_commit_chain(log, store, batch, values, slot, rows)
     it = _auto_interpret() if interpret is None else interpret

@@ -125,8 +125,7 @@ def paged_attention_stats(q, k_pages, v_pages, page_table, lengths, *,
             "ids": (rows + 2 * cols) * 4,
             "page": cols * hd * k_pages.dtype.itemsize,
             "out": rows * (hd + 2) * 4,
-        },
-        {},
+        }
     )
     whole = lambda shape: pl.BlockSpec(
         shape, lambda bb, p, pt, ln: (0,) * len(shape), memory_space=sp["ids"])

@@ -14,8 +14,17 @@ the row inside VMEM:
   ``scatter``: dst tile ``idx // S`` staged once, row ``idx % S`` patched
                in VMEM, written back when the walk leaves the tile. The
                targets must be sorted so each tile is visited in one run:
-               the aliased read of a tile is then never issued after a
-               write-back of the same tile.
+               the read of a tile is then never issued after a write-back
+               of the same tile.
+
+The destination is passed to ``pallas_call`` once, as the blocked operand
+aliased to the output, and is updated in place: passed a second time, as a
+separate read operand, it would make XLA copy the whole destination before
+every call so the read could not see the call's own writes. The runtime
+keeps a narrow array (a last dimension below 128 lanes) in a compact
+layout, so XLA still relayouts it into the row-major tiled layout a
+``tpu_custom_call`` takes and back around each call, until the state is
+stored lane-dense.
 """
 from __future__ import annotations
 
@@ -58,8 +67,7 @@ def gather(src, idx, *, interpret: bool = True):
     )(idx, src)
 
 
-def _scatter_kernel(idx_ref, dst_ref, vals_ref, tile_ref, out_ref):
-    del dst_ref  # aliased destination, present only to pin the in-place update
+def _scatter_kernel(idx_ref, vals_ref, tile_ref, out_ref):
     k, i = pl.program_id(0), pl.program_id(1)
     b = pl.num_programs(1)
     s, sv = out_ref.shape[1], vals_ref.shape[1]
@@ -77,7 +85,12 @@ def _scatter_kernel(idx_ref, dst_ref, vals_ref, tile_ref, out_ref):
 def scatter(dst, vals, idx, *, interpret: bool = True):
     """dst: (R, N, W); vals: (R, B, W); idx: (R, B) int32, each replica's
     row list sorted ascending. Writes ``dst[r, idx[r, i]] = vals[r, i]``
-    in place (a repeated row keeps its last value) and returns dst."""
+    in place (a repeated row keeps its last value) and returns dst.
+
+    ``dst`` is the one operand aliased to the output: its tile is read by
+    the first entry of a run and written back when the run ends, and the
+    sorted targets keep every tile in one run, so no tile is read after
+    its own write-back."""
     r, n, w = dst.shape
     b = idx.shape[1]
     s = sublanes(dst.dtype)
@@ -86,9 +99,8 @@ def scatter(dst, vals, idx, *, interpret: bool = True):
         num_scalar_prefetch=1,
         grid=(r, b),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # aliased dst
             pl.BlockSpec((1, s, w), lambda k, i, idx: (k, i // s, 0)),
-            tile,
+            tile,  # dst, aliased to the output
         ],
         out_specs=tile,
     )
@@ -97,6 +109,6 @@ def scatter(dst, vals, idx, *, interpret: bool = True):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(dst.shape, dst.dtype),
         # aliases index the full pallas_call operand list (prefetch included)
-        input_output_aliases={1: 0},
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(idx.reshape(r * b), dst, vals, dst)
+    )(idx.reshape(r * b), vals, dst)

@@ -21,6 +21,12 @@ pool's zero sentinel page (``serving.kv_cache``) and the KVS bucket/pool
 pad rows (``kernels.hash_probe``) — with their payloads zeroed, so
 nothing is concatenated onto or stripped off the O(state) log/store per
 replica commit.
+
+Each scatter passes its log or store to ``pallas_call`` once, aliased to
+the output, and updates it in place. The log and store are narrower than
+128 lanes, so XLA still relayouts each of them from the runtime's compact
+layout into the row-major one the call takes, and back, around every
+commit until the state is stored lane-dense.
 """
 from __future__ import annotations
 
@@ -53,8 +59,9 @@ def commit(log, store, batch, values, slot, rows, *, interpret: bool = True):
     absolute log slot (LC = the sentinel); rows: (B*M,) int32 store row
     per op (NK = the sentinel). Sentinel-targeted payloads are zeroed so
     dead duplicates write identical zeros (deterministic, sentinel stays
-    zero). Returns the updated (log, store), same shapes in as out — the
-    aliased scatters update the state in place, no padded copy."""
+    zero). Returns the updated (log, store), same shapes in as out — each
+    is passed to its scatter once and updated in place (XLA still
+    relayouts both around the call until the state is lane-dense)."""
     log_o, store_o = commit_chain(
         log[None], store[None], batch, values, slot[None], rows,
         interpret=interpret,
@@ -79,7 +86,8 @@ def commit_chain(log, store, batch, values, slot, rows, *,
     shared by every replica, or (R, B*M) per-replica rows — chain
     shortening (``transaction.chain_commit_apply``) points every op of a
     dead replica at its own sentinel row while live replicas still land.
-    Returns the updated (log, store), same shapes, aliased in place."""
+    Returns the updated (log, store), same shapes, each passed to its
+    scatter once and updated in place."""
     r, lcp, tw = log.shape
     _, nkp, vw = store.shape
     lc, nk = lcp - 1, nkp - 1

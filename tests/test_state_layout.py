@@ -9,6 +9,9 @@ dispatch. Pinned here at the jaxpr level (the pattern of
 ``test_lm_paged.test_paged_decode_scan_never_carries_the_pool``), plus
 the donation/aliasing behaviour the layout exists to enable and the
 hypothesis hygiene property that the sentinel rows stay zero forever.
+Each aliased scatter takes its destination once: a second, read-only
+operand of the same array would make XLA copy the whole array before the
+call, so the read could not see the call's own writes.
 """
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import kvstore as kv
 from repro.core import transaction as tx
+from repro.kernels import hash_probe, rows
 
 I32 = jnp.int32
 
@@ -91,23 +95,40 @@ def test_tx_commit_dispatch_materializes_no_padded_state_copy(backend):
     _assert_no_state_sized_pad_copies(jx.jaxpr, _tx_state_dims(TX_CFG))
 
 
+def _aliased_calls(jaxpr):
+    """Every pallas_call that declares input_output_aliases, each checked
+    to take each aliased input exactly once among its operands."""
+    aliased = [
+        eqn for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "pallas_call"
+        and tuple(eqn.params.get("input_output_aliases") or ())
+    ]
+    for eqn in aliased:
+        for i, _ in eqn.params["input_output_aliases"]:
+            var = eqn.invars[i]
+            n = sum(v is var for v in eqn.invars)
+            kernel = eqn.params["jaxpr"].debug_info.func_name
+            assert n == 1, (
+                f"aliased operand {i} of {kernel} is passed {n} times: "
+                f"XLA copies it whole before the call"
+            )
+    return aliased
+
+
 def test_pallas_scatters_alias_state_in_and_out():
     """The whole point of the resident layout: the scatter kernels' declared
     input_output_aliases survive to the dispatched jaxpr (no interposed
-    copy means the aliased operand IS the state buffer)."""
+    copy means the aliased operand IS the state buffer), and each aliased
+    state array is that call's operand once and only once."""
     s = kv.make(KV_CFG)
     keys = jnp.ones((8, KV_CFG.key_words), I32)
     vals = jnp.ones((8, KV_CFG.val_words), I32)
     jx = jax.make_jaxpr(
         lambda st, k, v: kv.put(st, k, v, backend="pallas")
     )(s, keys, vals)
-    aliased = [
-        eqn for eqn in _eqns(jx.jaxpr)
-        if eqn.primitive.name == "pallas_call"
-        and tuple(eqn.params.get("input_output_aliases") or ())
-    ]
     # commit_buckets (bucket_keys+bucket_ptr) and write_rows (pool)
-    assert len(aliased) >= 2, "expected aliased scatter pallas_calls"
+    assert len(_aliased_calls(jx.jaxpr)) >= 2, (
+        "expected aliased scatter pallas_calls")
 
     chain = tx.make_chain(TX_CFG)
     batch = jnp.zeros((6, tx.tx_words(TX_CFG)), I32).at[:, 0].set(1)
@@ -115,12 +136,57 @@ def test_pallas_scatters_alias_state_in_and_out():
         lambda c, b: tx.chain_commit_local(c, b, TX_CFG,
                                            kernel_backend="pallas")
     )(chain, batch)
-    aliased = [
-        eqn for eqn in _eqns(jx.jaxpr)
-        if eqn.primitive.name == "pallas_call"
-        and tuple(eqn.params.get("input_output_aliases") or ())
-    ]
-    assert aliased, "expected the fused tx_commit pallas_call to alias"
+    # the log scatter and the store scatter
+    assert len(_aliased_calls(jx.jaxpr)) >= 2, (
+        "expected the tx_commit pallas_calls to alias")
+
+
+def test_row_scatter_patches_tiles_in_place_like_numpy():
+    """``rows.scatter`` in interpret mode against numpy, R = 2 replicas of
+    five 8-row tiles plus the sentinel row: runs that cross tile
+    boundaries, several rows patched within one tile, replica 0's walk
+    ending on its last tile (the sentinel run) right before replica 1's
+    starts on its first, and untouched rows left as they were."""
+    n, w = 5 * 8 + 1, 4  # row 40 is the sentinel, alone in tile 5
+    idx = np.array([
+        [1, 3, 7, 8, 9, 15, 16, 30, 31, 32, 38, 39, 40, 40, 40, 40],
+        [0, 2, 5, 6, 7, 10, 17, 23, 24, 25, 31, 32, 33, 39, 40, 40],
+    ], np.int32)
+    rng = np.random.default_rng(3)
+    dst = rng.integers(1, 1 << 30, (2, n, w)).astype(np.int32)
+    dst[:, -1] = 0
+    vals = rng.integers(1, 1 << 30, (2, idx.shape[1], w)).astype(np.int32)
+    vals[idx == n - 1] = 0  # sentinel payloads are zero, as callers send
+    want = dst.copy()
+    for r in range(2):
+        want[r, idx[r]] = vals[r]
+    got = rows.scatter(jnp.asarray(dst), jnp.asarray(vals), jnp.asarray(idx),
+                       interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_commit_buckets_patches_blocks_in_place_like_numpy():
+    """``hash_probe.commit_buckets`` in interpret mode against numpy: two
+    ways of one bucket in one run, adjacent buckets sharing a pointer
+    tile, a bucket alone in its tile, and the sentinel bucket's run of
+    zero payloads; untouched ways keep their keys and pointers."""
+    nb, w, kw = 20, 4, 2  # bucket 20 is the sentinel, in pointer tile 2
+    tb = np.array([2, 2, 3, 5, 7, 8, 9, 15, 16, 20, 20], np.int32)
+    tw = np.array([0, 3, 1, 2, 3, 0, 1, 2, 0, 0, 1], np.int32)
+    rng = np.random.default_rng(4)
+    bk = rng.integers(1, 1 << 30, (nb + 1, w, kw)).astype(np.int32)
+    bp = rng.integers(0, 1 << 20, (nb + 1, w)).astype(np.int32)
+    bk[-1], bp[-1] = 0, 0
+    keys = rng.integers(1, 1 << 30, (tb.size, kw)).astype(np.int32)
+    pv = rng.integers(0, 1 << 20, tb.size).astype(np.int32)
+    keys[tb == nb], pv[tb == nb] = 0, 0
+    want_k, want_p = bk.copy(), bp.copy()
+    want_k[tb, tw], want_p[tb, tw] = keys, pv
+    got_k, got_p = hash_probe.commit_buckets(
+        jnp.asarray(bk), jnp.asarray(bp), jnp.asarray(keys), jnp.asarray(tb),
+        jnp.asarray(tw), jnp.asarray(pv), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_p), want_p)
 
 
 def test_donated_state_aliases_through_put_commit():

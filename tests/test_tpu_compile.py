@@ -10,7 +10,9 @@ a 3-replica TX chain over 2^20 64 B rows, 8 DLRM tables of 2^20 x 64 f32,
 and qwen1.5-0.5b's paged decode and prefill attention in bf16. The KVS and
 TX engine steps compile there too, at a small size, to check that each of
 their Pallas calls keeps the name ``bench/metrics/kernels.json`` matches and
-runs in its named phase; the DLRM-DCNv2 step compiles at its benchmark
+runs in its named phase, and at their benchmark cells' full sizes, to check
+that every in-place scatter takes its state array once and so leaves XLA
+no whole copy of it to make; the DLRM-DCNv2 step compiles at its benchmark
 cell's full size, to check that it reads its 6.78 GB table in place.
 
 The topology is described inside a fixture, never at import: only one
@@ -18,6 +20,7 @@ process may load the TPU compiler library, and every test worker imports
 this file.
 """
 import os
+import re
 import sys
 import types
 from pathlib import Path
@@ -153,21 +156,33 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             + mem.temp_size_in_bytes) < 16e9
 
 
-def _engine_step(app):
-    """The engine step of one app at a small size (the cells' widths), and
-    its state's shapes."""
+# the cells' widths at a small size, and at the benchmark cells' full sizes
+STEP_SIZES = {
+    "kvs": dict(num_buckets=64, ways=8, key_words=6, val_words=256,
+                pool_size=1024, cache_sets=16, cache_ways=4),
+    "tx": dict(num_keys=512, val_words=16, max_ops=8, chain_len=4,
+               log_capacity=64),
+}
+CELL_SIZES = {
+    "kvs": dict(STEP_SIZES["kvs"], num_buckets=NB, pool_size=NP, cache_sets=CS),
+    "tx": dict(STEP_SIZES["tx"], num_keys=2 ** 21, log_capacity=2 ** 18),
+}
+
+
+def _engine_step(app, sizes=STEP_SIZES, queues=2, capacity=8, budget=8):
+    """The engine step of one app at ``sizes[app]``, and its state's
+    shapes."""
     from repro.core import engine, kvstore, transaction, tx_app
 
     if app == "kvs":
-        cfg = kvstore.KVConfig(num_buckets=64, ways=8, key_words=6, val_words=256,
-                               pool_size=1024, cache_sets=16, cache_ways=4)
+        cfg = kvstore.KVConfig(**sizes[app])
         words, mod, make = kvstore.request_words(cfg), kvstore, kvstore.make
     else:
-        cfg = transaction.TxConfig(num_keys=512, val_words=16, max_ops=8,
-                                   chain_len=4, log_capacity=64)
+        cfg = transaction.TxConfig(**sizes[app])
         words, mod, make = tx_app.request_words(cfg), tx_app, transaction.make_chain
-    ecfg = engine.EngineConfig(num_queues=2, capacity=8, req_words=words,
-                               resp_words=words, budget=8, kernel_backend="auto")
+    ecfg = engine.EngineConfig(num_queues=queues, capacity=capacity,
+                               req_words=words, resp_words=words, budget=budget,
+                               kernel_backend="auto")
     app_fn = engine.bind_app(mod.app_step, cfg, ecfg)
     state = jax.eval_shape(lambda: engine.make(ecfg, make(cfg)))
     return (lambda s: engine.engine_step(s, app_fn, ecfg)), state
@@ -213,6 +228,76 @@ def test_engine_step_kernels_keep_names_and_phases(one_chip, monkeypatch, app):
         (kernel,) = [k for k in _ops.TABLE["kernels"] if _ops.in_kernel(trace, k, instr)]
         found.add((base_name(instr), kernel, phase_of(scopes[instr])))
     assert found == STEP_KERNELS[app]
+
+
+_DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+
+
+def _instructions(hlo_text: str) -> dict:
+    """Instruction name -> (result shape, opcode, operand names, rest of the
+    line) for every instruction of a compiled program."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _DEF.match(line)
+        if m:
+            args, _, rest = m.group(4).partition(")")
+            names = [a.strip().lstrip("%") for a in
+                     re.sub(r"/\*.*?\*/", "", args).split(",") if a.strip()]
+            out[m.group(1)] = (m.group(2), m.group(3), names, rest)
+    return out
+
+
+def _copied_from(instrs: dict, name: str) -> str:
+    """The instruction a chain of ``copy`` ops starts from."""
+    while name in instrs and instrs[name][1] == "copy":
+        name = instrs[name][2][0]
+    return name
+
+
+# temporaries of the full-size steps with each destination passed twice (a
+# whole copy of it made before every call): KVS 2,423,422,976 B, TX
+# 8,595,774,976 B
+STEP_TEMP_LIMIT = {"kvs": 2_423_422_976 - 1e9, "tx": 4.5e9}
+
+
+@pytest.mark.parametrize("app", ["kvs", "tx"])
+def test_cell_step_passes_each_scatter_destination_once(one_chip, monkeypatch,
+                                                        app):
+    """The KVS step (2^18 x 8 buckets of 6 key words, a 2^20 x 256 pool, a
+    1024 x 4 cache) and the TX step (4 replicas of 2^21 x 16 rows and 2^18
+    log records) at their benchmark cells' sizes, with the driver's engine
+    (8 queues x 64, 256 a step) and the state donated. No aliased Pallas
+    operand reaches its call beside another operand holding the same array,
+    whether passed as is or through a copy: XLA would copy the whole
+    destination before the call. What is left to copy is the relayout of
+    the narrow arrays into the layout a ``tpu_custom_call`` takes."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_auto_interpret", lambda: False)
+    step, state = _engine_step(app, CELL_SIZES, queues=8, capacity=64, budget=B)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state)
+    compiled = jax.jit(step, donate_argnums=0).lower(state).compile()
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    n_aliased = 0
+    for name, (_, op, args, rest) in instrs.items():
+        if op != "custom-call" or 'custom_call_target="tpu_custom_call"' not in rest:
+            continue
+        aliasing = re.search(r"output_to_operand_aliasing=\{(.*?)\}\}", rest)
+        if not aliasing:
+            continue
+        sources = [_copied_from(instrs, a) for a in args]
+        for i in map(int, re.findall(r"\((\d+), \{", aliasing.group(1))):
+            n_aliased += 1
+            assert sources.count(sources[i]) == 1, (
+                f"{name}: aliased operand {args[i]} holds the same array as "
+                f"another operand")
+    assert n_aliased == {"kvs": 3, "tx": 2}[app]
+    pool = re.compile(r"s32\[(1,)?1048577,256\]")
+    assert not [n for n, (shape, op, _, _) in instrs.items()
+                if op in ("copy", "copy-start") and pool.search(shape)]
+    assert compiled.memory_analysis().temp_size_in_bytes < STEP_TEMP_LIMIT[app]
 
 
 def test_dlrm_dcnv2_step_reads_the_table_in_place(one_chip, monkeypatch):
